@@ -59,7 +59,7 @@ from repro.graph.coloring import coloring_numpy
 from repro.graph.reorder import ORDERINGS, apply_order
 from repro.kernels.ema import ops as ema_ops
 from repro.kernels.fused import ops as fused_ops
-from repro.kernels.fused.pallas_fused import pick_batch_block
+from repro.kernels.fused.pallas_fused import pick_group_batch_block
 from repro.kernels.spmm import ops as spmm_ops
 
 DEFAULT_OUT = pathlib.Path(__file__).resolve().parents[1] / \
@@ -114,8 +114,8 @@ def _kernel_section(g, tmpl_name: str, peaks, *, batch: int,
     peak_flops, peak_bw = peaks
     engine = build_engine(g, tmpl_name, "pgbsc", fuse_spmm_ema=True)
     k = engine.k
-    fprep = fused_ops.prepare_fused(g, interpret=True)
-    bsr_prep = spmm_ops.prepare(g, "pallas_bsr", interpret=True)
+    fprep = fused_ops.prepare_fused(g)
+    bsr_prep = spmm_ops.prepare(g, "pallas_bsr")
     adj_bytes = int(np.asarray(fprep.arrays["blocks"]).nbytes)
     rng = np.random.default_rng(0)
     itemsize = jnp.dtype(jnp.float32).itemsize
@@ -132,13 +132,14 @@ def _kernel_section(g, tmpl_name: str, peaks, *, batch: int,
             lambda a, p: fused_ops.fused_spmm_ema(a, p, ia, ip, fprep))
         unfused = jax.jit(lambda a, p: ema_ops.ema(
             a, spmm_ops.spmm(p, bsr_prep), ia, ip,
-            use_pallas=True, interpret=True))
+            use_pallas=True))
 
         sec_f = timeit(fused, m_a, m_p, iters=reps)
         sec_u = timeit(unfused, m_a, m_p, iters=reps)
         flops = spmm_ema_flops(batch, g.m, g.n, c_p, s, length)
         s_pad = -(-s // 8) * 8
-        bb = pick_batch_block(batch, c_a, c_p, s_pad, length, 128, itemsize)
+        bb = pick_group_batch_block(batch, (c_a,), c_p, (s_pad,),
+                                    (length,), 128, jnp.float32)
         pair = {}
         for variant, sec in (("fused", sec_f), ("unfused", sec_u)):
             hbm = spmm_ema_hbm_bytes(
@@ -192,13 +193,13 @@ def _reorder_section(g) -> dict:
     """Occupied BSR blocks / density / fused grid steps, before vs after."""
     before = g.bsr_block_stats()
     grid_before = int(np.asarray(
-        fused_ops.prepare_fused(g, interpret=True).arrays["src_tile"]).size)
+        fused_ops.prepare_fused(g).arrays["src_tile"]).size)
     out = {"before": {**before, "fused_grid_steps": grid_before}}
     for name, fn in sorted(ORDERINGS.items()):
         gp = apply_order(g, fn(g))
         after = gp.bsr_block_stats()
         grid = int(np.asarray(
-            fused_ops.prepare_fused(gp, interpret=True)
+            fused_ops.prepare_fused(gp)
             .arrays["src_tile"]).size)
         out[name] = {**after, "fused_grid_steps": grid}
         emit(f"reorder/{name}/occupied_blocks", 0.0,
@@ -230,16 +231,16 @@ def _dtype_section(g, peaks, *, batch: int, reps: int) -> dict:
     out = {}
     for dt in (jnp.float32, jnp.bfloat16):
         dname = np.dtype(dt).name
-        prep = fused_ops.prepare_fused(g, interpret=True, dtype=dt)
+        prep = fused_ops.prepare_fused(g, dtype=dt)
         m_a, m_p = m_a32.astype(dt), m_p32.astype(dt)
         fn = jax.jit(
             lambda a, p, prep=prep: fused_ops.fused_spmm_ema(
                 a, p, ia, ip, prep))
         sec = timeit(fn, m_a, m_p, iters=reps)
         item = jnp.dtype(dt).itemsize
-        acc_item = jnp.dtype(ema_ops.accum_dtype(dt)).itemsize
         adj_bytes = int(np.asarray(prep.arrays["blocks"]).nbytes)
-        bb = pick_batch_block(batch, c_a, c_p, s_pad, length, 128, acc_item)
+        bb = pick_group_batch_block(batch, (c_a,), c_p, (s_pad,), (length,),
+                                    128, dt)
         hbm = spmm_ema_hbm_bytes(batch, g.n, c_a, c_p, s, adj_bytes, item,
                                  fused=True, adj_passes=-(-batch // bb))
         r = KernelRoofline(name=f"fused/{dname}", flops=flops,
@@ -284,7 +285,7 @@ def _shared_section(g, *, batch: int, reps: int) -> dict:
     cols_grouped = shared.spmm_cols_per_coloring
     cols_per_consumer = cols_grouped + (len(grp) - 1) * c_p
     rng = np.random.default_rng(3)
-    fprep = fused_ops.prepare_fused(g, interpret=True)
+    fprep = fused_ops.prepare_fused(g)
     m_p = jnp.asarray(rng.random((batch, c_p, g.n), np.float32))
     m_as, ias, ips = [], [], []
     for m in grp:

@@ -4,6 +4,12 @@ Thread scaling on the paper's Skylake node becomes device scaling of the
 shard_map ring engine here (1 real core under the hood, so this measures
 partitioning overhead, not true speedup — the trend of interest is that the
 ring decomposition stays correct and the per-device work shrinks).
+
+Each device count runs in a child process with that many fake host
+devices of the CPU backend. The bench therefore refuses to run where the
+default backend is an accelerator: a parent that has touched the chip holds
+it, and fake host devices would measure nothing about the chip anyway. A
+child that fails fails the bench.
 """
 
 from __future__ import annotations
@@ -17,7 +23,6 @@ from benchmarks.common import emit
 
 _WORKER = """
 import os, sys, json, time
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%d"
 import jax
 from repro.core import get_template
 from repro.core.distributed import DistributedPgbsc
@@ -52,17 +57,25 @@ print(json.dumps(rec))
 
 
 def run() -> dict:
+    import jax
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"fig13_scaling fakes host devices on the CPU backend; the "
+            f"default backend here is {jax.default_backend()!r}")
     out = {}
     counts = {}
     for d in (1, 2, 4, 8):
         env = dict(os.environ)
         env["PYTHONPATH"] = "src"
+        env["JAX_PLATFORMS"] = "cpu"
+        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " --xla_force_host_"
+                            f"platform_device_count={d}").strip()
         proc = subprocess.run(
-            [sys.executable, "-c", _WORKER % (d, d)], env=env,
+            [sys.executable, "-c", _WORKER % d], env=env,
             capture_output=True, text=True, timeout=600)
         if proc.returncode != 0:
-            emit(f"fig13/devices{d}", -1, "FAILED")
-            continue
+            raise RuntimeError(f"fig13 worker with {d} devices failed:\n"
+                               f"{proc.stderr[-4000:]}")
         rec = json.loads(proc.stdout.strip().splitlines()[-1])
         emit(f"fig13/devices{d}", rec["sec"] * 1e6,
              f"count={rec['count']:.6g}")

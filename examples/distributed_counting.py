@@ -1,17 +1,18 @@
 """End-to-end driver: fault-tolerant distributed subgraph counting service.
 
-Runs the paper's workload (PGBSC on an RMAT graph) across a simulated
-8-device (pod=2, data=2, model=2) mesh with per-iteration checkpointing —
-kill it mid-run and rerun: it resumes from the ledger.
+Runs the paper's workload (PGBSC on an Erdos-Renyi graph) across every
+device JAX sees, with per-iteration checkpointing — kill it mid-run and
+rerun: it resumes from the ledger. Eight or more devices form a
+(pod=2, data=n/4, model=2) mesh, fewer a (data=n, model=1) one. On a host
+without accelerators, simulate eight devices:
 
-    PYTHONPATH=src python examples/distributed_counting.py [--iters 32]
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
+        PYTHONPATH=src python examples/distributed_counting.py [--iters 32]
 """
-
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import argparse
 
+import jax
 
 from repro.core import count_subgraphs_exact, get_template
 from repro.core.distributed import DistributedPgbsc
@@ -26,7 +27,10 @@ args = ap.parse_args()
 
 g = erdos_renyi(200, 6.0, seed=4)
 t = get_template("u5")
-mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
+n_dev = len(jax.devices())
+mesh = (make_mesh((2, n_dev // 4, 2), ("pod", "data", "model"))
+        if n_dev >= 8 and n_dev % 4 == 0
+        else make_mesh((n_dev, 1), ("data", "model")))
 print(f"mesh: {dict(mesh.shape)}  graph: n={g.n} m={g.m}  template: {t}")
 
 dist = DistributedPgbsc(g, t, mesh)

@@ -38,6 +38,7 @@ from repro.core.colorsets import colorful_probability
 from repro.core.engines import CountingEngine, build_engine
 from repro.core.motif_features import motif_features
 from repro.core.templates import TemplateSpec, as_template
+from repro.launch.compile_cache import enable_compile_cache
 from repro.service.requests import RequestResult, RunningStat
 
 __all__ = [
@@ -184,7 +185,9 @@ class CompiledQuery:
 def compile_query(g, query: CountQuery, engine_cache=None) -> CompiledQuery:
     """Lower a :class:`CountQuery` onto ``g``: one fused engine per k-group
     (served from ``engine_cache`` when given — keys are canonical hashes,
-    so two spellings of the same tree share one engine)."""
+    so two spellings of the same tree share one engine). Turns on the
+    persistent compilation cache first (:func:`enable_compile_cache`)."""
+    enable_compile_cache()
     return CompiledQuery(g, query, engine_cache=engine_cache)
 
 

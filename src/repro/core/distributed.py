@@ -300,8 +300,6 @@ class DistributedPgbsc:
         step_fn(seeds, src_l, dst_l, msk) -> per-pod colorful sums (or scalar
         for a single-pod mesh). ``seeds`` is int32 (n_pods,) [or (1,)].
         """
-        from jax.experimental.shard_map import shard_map
-
         split_tabs = {
             i: (jnp.asarray(m.ia), jnp.asarray(m.ip))
             for i, m in enumerate(self.meta) if m.ia is not None
@@ -334,9 +332,9 @@ class DistributedPgbsc:
         )
         out_specs = P("pod") if self.has_pod else P(None)
 
-        step = shard_map(
+        step = jax.shard_map(
             local_step, mesh=self.mesh, in_specs=in_specs,
-            out_specs=out_specs, check_rep=False,
+            out_specs=out_specs, check_vma=False,
         )
 
         if self.abstract:

@@ -28,6 +28,7 @@ transform (SpMM vs. hoisted neighbor sum vs. none) and the combine step.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from math import comb
 from typing import Callable
 
@@ -41,6 +42,7 @@ from repro.core.templates import (ExecutionPlan, as_template,
                                   compile_fused_plan)
 from repro.graph.reorder import ORDERINGS, apply_order, inverse_order
 from repro.graph.structure import Graph
+from repro.kernels import resolve_interpret
 from repro.kernels.ema import ops as ema_ops
 from repro.kernels.fused import ops as fused_ops
 from repro.kernels.spmm import ops as spmm_ops
@@ -136,7 +138,7 @@ class CountingEngine:
 
     def __init__(self, g: Graph, template, engine: str = "pgbsc",
                  spmm_method: str = "segment", use_pallas_ema: bool = False,
-                 interpret: bool = True, dedup: bool = False,
+                 interpret: bool | None = None, dedup: bool = False,
                  plan: str | None = None, dtype=jnp.float32,
                  batch_size: int | None = None,
                  memory_budget_bytes: int | None = None,
@@ -207,7 +209,7 @@ class CountingEngine:
                 "optimized": self.template.plan_optimized}[plan_name]
             self.roots = (self.plan.n_nodes - 1,)
         self.use_pallas_ema = use_pallas_ema
-        self.interpret = interpret
+        self.interpret = resolve_interpret(interpret)
         self.autotune_blocks = autotune_blocks
         self.fuse_spmm_ema = bool(fuse_spmm_ema and engine == "pgbsc")
         # per-node fusion decisions (idx -> "admitted" | "admitted_shared" |
@@ -290,11 +292,6 @@ class CountingEngine:
             return (comb(self.k, t_a), comb(self.k, t - t_a),
                     comb(self.k, t), comb(t, t_a))
 
-        def solo_fits(idx: int) -> bool:
-            c_a, c_p, s, l = dims(idx)
-            return fused_ops.fused_fits_vmem(c_a, c_p, s, l=l,
-                                             dtype=self.dtype)
-
         def group_fits(members: list[int]) -> bool:
             c_p = dims(members[0])[1]
             c_as = [dims(m)[0] for m in members]
@@ -320,7 +317,7 @@ class CountingEngine:
             if not dtype_ok:
                 self.fusion_report[idx] = "dtype_unsupported"
             elif len(consumers[node.passive]) == 1:
-                if solo_fits(idx):
+                if group_fits([idx]):
                     self.fusion_report[idx] = "admitted"
                     out.append(idx)
                 else:
@@ -334,7 +331,7 @@ class CountingEngine:
                 if len(cons) < 2:
                     continue
                 mset = set(cons)
-                if (all(solo_fits(i) for i in cons)
+                if (all(group_fits([i]) for i in cons)
                         and not any(self.plan.nodes[m].active in mset
                                     for m in cons)
                         and group_fits(cons)
@@ -410,6 +407,7 @@ class CountingEngine:
         self._count_fn = jax.jit(self._build())
         self._batch_fn = None    # built lazily on first batched call
         self._seeded_fn = None   # jit(seed, iteration ids) -> batch totals
+        self._warm_widths: set[int] = set()   # seeded widths compiled
         self._released = False
         # trace-time watermark: peak live table bytes observed by the
         # executor's on_step probe (high-watermark across traced shapes)
@@ -424,6 +422,15 @@ class CountingEngine:
             "engine_spmm_cols_dispatched_total", engine=self.engine)
         self._mem_labels = dict(engine=self.engine, template=label,
                                 k=self.k)
+
+    def _operands(self) -> dict:
+        """The graph-sized device arrays every count program reads. They
+        are passed to the jitted programs as arguments: an array a program
+        closes over is embedded in it as a constant, and at RMAT scale 20
+        that makes each executable hundreds of MB to compile and cache."""
+        return {"spmm": self._spmm_prep, "fused": self._fused_prep,
+                "nbr": self._nbr, "mask": self._mask,
+                "order": self._order_dev, "inv": self._inv_dev}
 
     def _peak_probe(self, step: int, live_bytes: int) -> None:
         """Executor ``on_step`` hook: record the measured (trace-time) peak
@@ -467,6 +474,7 @@ class CountingEngine:
                 except Exception:
                     pass
         self._count_fn = self._batch_fn = self._seeded_fn = None
+        self._warm_widths = set()
         self._spmm_prep = None
         self._fused_prep = None
         self._nbr = self._mask = None
@@ -490,7 +498,7 @@ class CountingEngine:
         self.n_spmm_cols_dispatched += self.spmm_cols_per_coloring
         self._m_spmm_cols.inc(self.spmm_cols_per_coloring)
         with _tracing.span("engine.dispatch", engine=self.engine, batch=1):
-            out = self._count_fn(jnp.asarray(colors))
+            out = self._count_fn(self._operands(), jnp.asarray(colors))
             _tracing.sync_ready(out)
         self._publish_memory_gauges(1)
         return out
@@ -536,7 +544,7 @@ class CountingEngine:
             first = self.n_batch_dispatches == 0
             with _tracing.span("engine.dispatch", engine=self.engine,
                                batch=bs, first=first):
-                tot, root = self._batch_fn(chunk)
+                tot, root = self._batch_fn(self._operands(), chunk)
                 _tracing.sync_ready(tot)
             self.n_batch_dispatches += 1
             self.n_colorings_dispatched += bs
@@ -573,18 +581,9 @@ class CountingEngine:
         its = [int(i) for i in iterations]
         if not its:
             return {}
-        # same clamping tradeoff as count_colorful_batch
-        bs = min(batch_size or self.batch_size or len(its), len(its))
-        if self._seeded_fn is None:
-            n, k = self.g.n, self.k
-
-            def seeded(seed_, ids):
-                from repro.graph.coloring import batch_colorings
-                colorings = batch_colorings(seed_, ids, n, k)
-                totals, _ = self._build_batch()(colorings)
-                return totals
-
-            self._seeded_fn = jax.jit(seeded)
+        bs = self._dispatch_width(len(its), batch_size)
+        seeded_fn = self._seeded()
+        ops = self._operands()
         out: dict = {}
         for base in range(0, len(its), bs):
             chunk = its[base: base + bs]
@@ -594,8 +593,8 @@ class CountingEngine:
                                batch=bs, first=first):
                 # np.asarray already blocks on the device result, so this
                 # span measures real device time without an extra sync
-                totals = np.asarray(self._seeded_fn(
-                    jnp.int32(seed), jnp.asarray(padded, jnp.int32)))
+                totals = np.asarray(seeded_fn(
+                    ops, jnp.int32(seed), jnp.asarray(padded, jnp.int32)))
             self.n_batch_dispatches += 1
             self.n_colorings_dispatched += bs
             self.n_spmm_cols_dispatched += self.spmm_cols_per_coloring * bs
@@ -606,6 +605,44 @@ class CountingEngine:
                 out[it] = totals[i].copy() if self.fused else float(totals[i])
         self._publish_memory_gauges(bs)
         return out
+
+    def _dispatch_width(self, n_ids: int, batch_size: int | None) -> int:
+        """Colorings per seeded dispatch for a call of ``n_ids`` ids —
+        clamped to the call, the same tradeoff as count_colorful_batch."""
+        return min(batch_size or self.batch_size or n_ids, n_ids)
+
+    def _seeded(self) -> Callable:
+        """jit(operands, seed, iteration ids) -> batch totals, built on
+        first use."""
+        if self._seeded_fn is None:
+            n, k = self.g.n, self.k
+
+            def seeded(ops, seed_, ids):
+                from repro.graph.coloring import batch_colorings
+                colorings = batch_colorings(seed_, ids, n, k)
+                totals, _ = self._build_batch()(ops, colorings)
+                return totals
+
+            self._seeded_fn = jax.jit(seeded)
+        return self._seeded_fn
+
+    def warm(self, n_ids: int, batch_size: int | None = None) -> None:
+        """Compile, without running, the seeded dispatch program that a
+        :meth:`count_iterations_batch` call of ``n_ids`` ids will launch.
+
+        The service calls this when it builds a dispatch group, so the
+        compile is build time and the dispatch watchdog times execution
+        only. A width compiled once is not compiled again.
+        """
+        self._ensure()
+        bs = self._dispatch_width(int(n_ids), batch_size)
+        if bs < 1 or bs in self._warm_widths:
+            return
+        with _tracing.span("engine.warm", engine=self.engine, batch=bs):
+            self._seeded().lower(
+                self._operands(), jax.ShapeDtypeStruct((), jnp.int32),
+                jax.ShapeDtypeStruct((bs,), jnp.int32)).compile()
+        self._warm_widths.add(bs)
 
     def estimate(self, n_iters: int, seed: int = 0,
                  start_iteration: int = 0,
@@ -663,14 +700,14 @@ class CountingEngine:
         """
         if self._order is None:
             return fn
-        order_dev, inv_dev = self._order_dev, self._inv_dev
         # pgbsc tables are combination-major (..., C, N); fascia/pfascia are
         # row-major (..., N, C) — the vertex axis moves accordingly
         vaxis = -1 if self.engine == "pgbsc" else -2
         is_fused = self.fused
 
-        def wrapped(colors: jax.Array):
-            totals, roots = fn(jnp.take(colors, order_dev, axis=-1))
+        def wrapped(ops: dict, colors: jax.Array):
+            inv_dev = ops["inv"]
+            totals, roots = fn(ops, jnp.take(colors, ops["order"], axis=-1))
             if is_fused:
                 roots = tuple(jnp.take(r, inv_dev, axis=vaxis)
                               for r in roots)
@@ -696,7 +733,8 @@ class CountingEngine:
         if self.engine == "pgbsc":
             return self._wrap_reorder(self._build_pgbsc())
         return self._wrap_reorder(
-            jax.vmap(self._build_rowmajor(pruned=self.engine == "pfascia")))
+            jax.vmap(self._build_rowmajor(pruned=self.engine == "pfascia"),
+                     in_axes=(None, 0)))
 
     def _leaf_table_cn(self, colors: jax.Array) -> jnp.ndarray:
         """(..., k, N) one-hot of vertex colors — combination-major leaves.
@@ -707,15 +745,16 @@ class CountingEngine:
                 == colors[..., None, :]).astype(self.dtype)
 
     def _build_pgbsc(self) -> Callable:
-        splits, packs, prep = self._splits, self._chunk_packs, self._spmm_prep
-        fprep = self._fused_prep
+        splits, packs = self._splits, self._chunk_packs
         runner = pexec.PlanExecutor(self.plan, self.schedule)
         autotune = self.autotune_blocks
 
-        def passive_op(p_idx, m_p):
+        # the callbacks taking ``ops`` get the traced dispatch's graph
+        # operands (:meth:`_operands`) bound by ``run``
+        def passive_op(ops, p_idx, m_p):
             # SpMM over *all* passive color sets at once (Algorithm 4 l.3);
             # with plan dedup, shared passive children reuse the result.
-            return spmm_ops.spmm(m_p, prep, autotune=autotune)
+            return spmm_ops.spmm(m_p, ops["spmm"], autotune=autotune)
 
         def combine(idx, m_a, y_p):
             ia, ip = splits[idx]
@@ -724,7 +763,7 @@ class CountingEngine:
                 use_pallas=self.use_pallas_ema, interpret=self.interpret,
                 autotune=autotune)
 
-        def combine_direct(idx, m_a, m_p):
+        def combine_direct(ops, idx, m_a, m_p):
             # direct (no materialized y_p) nodes; chunking wins over fusion
             # when the memory model assigned both (Schedule.fused_set doc)
             if idx in packs:
@@ -732,32 +771,34 @@ class CountingEngine:
                 # and consumed one C(k, t_p)-axis slice at a time
                 return ema_ops.ema_chunked(
                     m_a, m_p, packs[idx],
-                    lambda m: spmm_ops.spmm(m, prep, autotune=autotune))
+                    lambda m: spmm_ops.spmm(m, ops["spmm"],
+                                            autotune=autotune))
             # fused node: SpMM and eMA in one Pallas launch — the
             # (B, C(k,t_p), N) neighbor-sum table never leaves VMEM
             ia, ip = splits[idx]
-            return fused_ops.fused_spmm_ema(m_a, m_p, ia, ip, fprep)
+            return fused_ops.fused_spmm_ema(m_a, m_p, ia, ip, ops["fused"])
 
-        def combine_group(members, m_as, m_p):
+        def combine_group(ops, members, m_as, m_p):
             # shared-passive group: ONE launch computes the passive child's
             # neighbor sums once in VMEM scratch and applies every member's
             # split combination against it
             ias = tuple(splits[m][0] for m in members)
             ips = tuple(splits[m][1] for m in members)
             return fused_ops.fused_spmm_ema_shared(m_as, m_p, ias, ips,
-                                                   fprep)
+                                                   ops["fused"])
 
         # sub-f32 storage sums its root tables in the accumulator dtype
         # (f32 for bf16) — the final reduction must not halve its mantissa
         acc_dt = ema_ops.accum_dtype(self.dtype)
 
-        def run(colors: jax.Array):
+        def run(ops: dict, colors: jax.Array):
             # colors: (N,) or batched (B, N) — every step below is
             # polymorphic over the leading batch dimension.
             leaf = self._leaf_table_cn(colors)
-            outs = runner.run(leaf, passive_op=passive_op, combine=combine,
-                              combine_direct=combine_direct,
-                              combine_group=combine_group,
+            outs = runner.run(leaf, passive_op=partial(passive_op, ops),
+                              combine=combine,
+                              combine_direct=partial(combine_direct, ops),
+                              combine_group=partial(combine_group, ops),
                               on_step=self._peak_probe,
                               outputs=self.roots)
             if not self.fused:
@@ -774,12 +815,13 @@ class CountingEngine:
     def _build_rowmajor(self, pruned: bool) -> Callable:
         """FASCIA / PFASCIA: row-major (N, C) tables + ELL traversal."""
         splits = self._splits
-        nbr, mask = self._nbr, self._mask
         runner = pexec.PlanExecutor(self.plan, self.schedule)
 
         acc_dt = ema_ops.accum_dtype(self.dtype)
 
-        def nbr_sum(m_cols: jnp.ndarray) -> jnp.ndarray:
+        # the functions taking ``ops`` get the traced dispatch's ELL
+        # operands (:meth:`_operands`) bound by ``run``
+        def nbr_sum(ops, m_cols: jnp.ndarray) -> jnp.ndarray:
             # m_cols: (N, R) -> out[i, r] = sum_d m_cols[nbr[i, d], r] * mask
             # Accumulate in acc_dt (f32 for bf16 tables) and downcast once at
             # the end — the scan carry must keep one dtype throughout.
@@ -789,12 +831,12 @@ class CountingEngine:
                 return acc + gathered * msk.astype(acc_dt)[:, None], None
 
             acc0 = jnp.zeros(m_cols.shape, acc_dt)
-            acc, _ = jax.lax.scan(body, acc0, (nbr.T, mask.T))
+            acc, _ = jax.lax.scan(body, acc0, (ops["nbr"].T, ops["mask"].T))
             return acc.astype(m_cols.dtype)
 
-        def passive_op(p_idx, m_p):
+        def passive_op(ops, p_idx, m_p):
             # PFASCIA: one neighbor sweep per distinct passive set.
-            return nbr_sum(m_p)
+            return nbr_sum(ops, m_p)
 
         def combine(idx, m_a, y_p):
             ia, ip = splits[idx]
@@ -809,13 +851,13 @@ class CountingEngine:
             acc, _ = jax.lax.scan(body, acc0, (ia.T, ip.T))
             return acc.astype(self.dtype)
 
-        def combine_direct(idx, m_a, m_p):
+        def combine_direct(ops, idx, m_a, m_p):
             # FASCIA: the neighbor sweep is *inside* the split loop —
             # the redundancy of paper §3.1, preserved deliberately.
             ia, ip = splits[idx]
 
             def body(acc, idx_l):
-                y_l = nbr_sum(m_p[:, idx_l[1]])   # (N, S) sweep per split
+                y_l = nbr_sum(ops, m_p[:, idx_l[1]])   # (N, S) per split
                 prod = m_a[:, idx_l[0]].astype(acc_dt) * y_l.astype(acc_dt)
                 return acc + prod, None
 
@@ -823,12 +865,12 @@ class CountingEngine:
             acc, _ = jax.lax.scan(body, acc0, (ia.T, ip.T))
             return acc.astype(self.dtype)
 
-        def run(colors: jax.Array):
+        def run(ops: dict, colors: jax.Array):
             leaf = self._leaf_table_cn(colors).T  # (N, k)
             outs = runner.run(
                 leaf,
-                passive_op=None if not pruned else passive_op,
-                combine=combine, combine_direct=combine_direct,
+                passive_op=partial(passive_op, ops) if pruned else None,
+                combine=combine, combine_direct=partial(combine_direct, ops),
                 on_step=self._peak_probe,
                 outputs=self.roots)
             if not self.fused:

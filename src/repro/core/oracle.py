@@ -24,11 +24,14 @@ __all__ = [
 ]
 
 
-def _embed(g: Graph, t: TreeTemplate, accept) -> int:
+def _embed(g: Graph, t: TreeTemplate, accept, admit=None) -> int:
     """Count injective homomorphisms T -> G, filtered by ``accept(mapping)``.
 
     Template vertices are assigned in BFS order from the template root so each
     newly placed vertex has exactly one already-placed neighbor (tree).
+    ``admit(pos, gv)``, when given, may reject graph vertex ``gv`` for
+    template position ``pos`` of a partial mapping; it must only reject
+    what ``accept`` would reject in every completion.
     """
     order = [t.root]
     parent = {t.root: -1}
@@ -55,6 +58,8 @@ def _embed(g: Graph, t: TreeTemplate, accept) -> int:
             candidates = g.neighbors(int(mapping[parent[tv]]))
         for gv in candidates:
             gv = int(gv)
+            if admit is not None and not admit(pos, gv):
+                continue
             if not used[gv]:
                 used[gv] = True
                 mapping[tv] = gv
@@ -71,13 +76,20 @@ def count_embeddings(g: Graph, t: TreeTemplate) -> int:
 
 
 def count_colorful_embeddings(g: Graph, t: TreeTemplate, colors: np.ndarray) -> int:
-    colors = np.asarray(colors)
+    colors = np.asarray(colors).tolist()
+    # colors taken by positions < pos of the current partial mapping: a
+    # repeated color can never complete to a colorful embedding, so that
+    # branch is cut where it starts instead of enumerated to its leaves
+    taken = [0] * (t.k + 1)
 
-    def accept(mapping):
-        cs = colors[mapping]
-        return len(set(cs.tolist())) == t.k
+    def admit(pos, gv):
+        bit = 1 << colors[gv]
+        if taken[pos] & bit:
+            return False
+        taken[pos + 1] = taken[pos] | bit
+        return True
 
-    return _embed(g, t, accept)
+    return _embed(g, t, lambda mapping: True, admit)
 
 
 def count_subgraphs_exact(g: Graph, t: TreeTemplate) -> float:

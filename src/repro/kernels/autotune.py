@@ -87,7 +87,7 @@ def autotune(key: Hashable, candidates: Sequence, make_fn: Callable,
     return best
 
 
-def ema_blocks(m_a, y_p, ia, ip, *, interpret: bool,
+def ema_blocks(m_a, y_p, ia, ip, *, interpret: bool | None = None,
                candidates: Sequence[tuple[int, int]] = EMA_BLOCK_CANDIDATES
                ) -> tuple[int, int]:
     """Tuned (s_block, n_block) for :func:`..ema.pallas_ema.ema_pallas`.
@@ -95,7 +95,9 @@ def ema_blocks(m_a, y_p, ia, ip, *, interpret: bool,
     The key carries the backend kind, both table dtypes, and the interpret
     flag alongside the shapes — a bf16 sweep never reuses f32 timings. (The
     eMA kernel has no graph operand, so no reorder component here.)"""
+    from repro.kernels import resolve_interpret
     from repro.kernels.ema.pallas_ema import ema_pallas
+    interpret = resolve_interpret(interpret)
     key = ("ema", m_a.shape, y_p.shape, ia.shape, str(m_a.dtype),
            str(y_p.dtype), interpret)
 

@@ -18,16 +18,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import kernels
+from repro.kernels import accum_dtype, resolve_interpret
 from repro.kernels import autotune as _autotune
-from repro.kernels.ema.pallas_ema import ema_pallas
+from repro.kernels.ema.pallas_ema import ema_pallas, ema_vmem_bytes
 from repro.obs import metrics as _metrics
 
 __all__ = ["ema", "ema_xla", "ema_chunked", "pack_chunked_splits",
            "ChunkedSplits", "ema_flops", "pallas_supports_dtype",
            "pallas_dtype_pair", "accum_dtype"]
 
-# VMEM budget for the Pallas path: both child tables + out block.
-_PALLAS_VMEM_BYTES = 12 * 2 ** 20
 _PALLAS_S_BLOCK = 8
 _PALLAS_N_BLOCK = 512
 
@@ -48,31 +48,24 @@ _COMPILED_PAIRS = {
 }
 
 
-def pallas_dtype_pair(dtype, interpret: bool
+def pallas_dtype_pair(dtype, interpret: bool | None = None
                       ) -> tuple[np.dtype, np.dtype] | None:
     """(storage, accumulator) dtype pair for the Pallas kernels, or None.
 
     None means the kernels cannot run this dtype in this mode without
-    downcasting — the dispatch layers fall back to XLA explicitly.
+    downcasting — the dispatch layers fall back to XLA explicitly. The
+    mode is ``resolve_interpret(interpret)``.
     """
     dt = np.dtype(dtype)
-    table = _INTERPRET_PAIRS if interpret else _COMPILED_PAIRS
+    table = (_INTERPRET_PAIRS if resolve_interpret(interpret)
+             else _COMPILED_PAIRS)
     acc = table.get(dt)
     return None if acc is None else (dt, acc)
 
 
-def pallas_supports_dtype(dtype, interpret: bool) -> bool:
+def pallas_supports_dtype(dtype, interpret: bool | None = None) -> bool:
     """Whether the Pallas kernels can run this dtype *without* downcasting."""
     return pallas_dtype_pair(dtype, interpret) is not None
-
-
-def accum_dtype(dtype) -> np.dtype:
-    """Accumulator dtype for a storage dtype: sub-f32 storage accumulates
-    in f32 (kernel scratch AND the XLA fallback paths), wider passes
-    through. This is the \"final reductions in f32\" half of the bf16
-    contract — storage is narrow, arithmetic is not."""
-    dt = np.dtype(dtype)
-    return np.dtype(jnp.float32) if dt.itemsize < 4 else dt
 
 
 def ema_xla(m_a: jnp.ndarray, y_p: jnp.ndarray,
@@ -96,7 +89,7 @@ def ema_xla(m_a: jnp.ndarray, y_p: jnp.ndarray,
 
 
 def ema(m_a: jnp.ndarray, y_p: jnp.ndarray, ia: jnp.ndarray, ip: jnp.ndarray,
-        *, use_pallas: bool = False, interpret: bool = True,
+        *, use_pallas: bool = False, interpret: bool | None = None,
         s_block: int | None = None, n_block: int | None = None,
         autotune: bool = False) -> jnp.ndarray:
     """eMA dispatch. ``use_pallas`` selects the kernel path when the dtype is
@@ -106,6 +99,7 @@ def ema(m_a: jnp.ndarray, y_p: jnp.ndarray, ia: jnp.ndarray, ip: jnp.ndarray,
     :data:`repro.kernels.autotune.EMA_BLOCK_CANDIDATES` once per shape."""
     dtype = jnp.promote_types(m_a.dtype, y_p.dtype)
     if use_pallas:
+        interpret = resolve_interpret(interpret)
         if not pallas_supports_dtype(dtype, interpret):
             _metrics.counter("kernel_fallbacks_total", kernel="ema",
                              reason="dtype_unsupported").inc()
@@ -128,12 +122,11 @@ def ema(m_a: jnp.ndarray, y_p: jnp.ndarray, ia: jnp.ndarray, ip: jnp.ndarray,
 
 def _fits_vmem(m_a, y_p, *, n_block: int = _PALLAS_N_BLOCK,
                s_block: int = _PALLAS_S_BLOCK) -> bool:
-    """VMEM residency check at the *actual* block sizes and itemsize: both
-    child tables (full combination axis, one n_block of lanes) plus the
-    (s_block, n_block) output block."""
-    itemsize = np.dtype(jnp.promote_types(m_a.dtype, y_p.dtype)).itemsize
-    rows = m_a.shape[-2] + y_p.shape[-2] + s_block
-    return rows * n_block * itemsize < _PALLAS_VMEM_BYTES
+    """VMEM residency check at the *actual* block sizes and dtype: the
+    kernel's whole per-step working set (:func:`ema_vmem_bytes`)."""
+    dtype = jnp.promote_types(m_a.dtype, y_p.dtype)
+    return ema_vmem_bytes(m_a.shape[-2], y_p.shape[-2], s_block, n_block,
+                          dtype) < kernels.VMEM_BUDGET_BYTES
 
 
 # ------------------------------------------------------------------ chunked

@@ -33,14 +33,59 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["ema_pallas"]
+from repro.kernels import accum_dtype, resolve_interpret, vmem_bytes
+
+__all__ = ["ema_pallas", "ema_vmem_bytes"]
 
 
-def _kernel(ia_ref, ip_ref, ma_ref, yp_ref, out_ref, *, s_block: int, l: int,
-            s_total: int, acc_dtype):
+def _scratch_shapes(c_a: int, c_p: int, s_block: int, n_block: int, dtype):
+    """Row accumulator, plus the widened copies of both resident child
+    blocks for sub-32-bit storage (see ``_kernel``)."""
+    acc = accum_dtype(dtype)
+    shapes = [pltpu.VMEM((s_block, n_block), acc)]
+    if jnp.dtype(dtype).itemsize < 4:
+        shapes += [pltpu.VMEM((c_a, n_block), acc),
+                   pltpu.VMEM((c_p, n_block), acc)]
+    return shapes
+
+
+def ema_vmem_bytes(c_a: int, c_p: int, s_block: int, n_block: int,
+                   dtype) -> int:
+    """VMEM working set of one grid step: both child blocks and the output
+    block double-buffered in the storage dtype, plus the scratch of
+    :func:`_scratch_shapes`."""
+    blocks = sum(vmem_bytes(r, n_block, dtype, copies=2)
+                 for r in (c_a, c_p, s_block))
+    return blocks + sum(vmem_bytes(*sh.shape, sh.dtype)
+                        for sh in _scratch_shapes(c_a, c_p, s_block,
+                                                  n_block, dtype))
+
+
+def _kernel(ia_ref, ip_ref, ma_ref, yp_ref, out_ref, rows_ref, *widened,
+            s_block: int, l: int, s_total: int):
     sb = pl.program_id(1)
-    n_blk = out_ref.shape[-1]
-    dtype = out_ref.dtype
+    acc_dtype = rows_ref.dtype
+    n_blk = rows_ref.shape[-1]
+    if widened:
+        # packed sub-32-bit tables (bf16 holds two rows per 32-bit sublane):
+        # Mosaic loads a single dynamic row only at 32-bit granularity, so
+        # the resident blocks are widened once per grid step into
+        # accumulator-dtype scratch and the rows are gathered from there
+        ma_w, yp_w = widened
+        ma_w[...] = ma_ref[0].astype(acc_dtype)
+        yp_w[...] = yp_ref[0].astype(acc_dtype)
+
+        def a_row(i):
+            return ma_w[pl.dslice(i, 1), :]
+
+        def p_row(i):
+            return yp_w[pl.dslice(i, 1), :]
+    else:
+        def a_row(i):
+            return ma_ref[0, pl.dslice(i, 1), :]
+
+        def p_row(i):
+            return yp_ref[0, pl.dslice(i, 1), :]
 
     def s_body(s, _):
         s_global = sb * s_block + s
@@ -49,23 +94,23 @@ def _kernel(ia_ref, ip_ref, ma_ref, yp_ref, out_ref, *, s_block: int, l: int,
             # rows accumulate in acc_dtype (f32 for bf16 storage) and cast
             # at the store, so narrow tables never pay accumulation error
             def l_body(j, row):
-                ia = ia_ref[s_global, j]
-                ip = ip_ref[s_global, j]
-                a_row = ma_ref[0, pl.dslice(ia, 1), :]   # (1, N_BLK)
-                p_row = yp_ref[0, pl.dslice(ip, 1), :]   # (1, N_BLK)
-                return row + a_row.astype(acc_dtype) * p_row.astype(acc_dtype)
+                a = a_row(ia_ref[s_global, j]).astype(acc_dtype)
+                p = p_row(ip_ref[s_global, j]).astype(acc_dtype)
+                return row + a * p
 
             return jax.lax.fori_loop(0, l, l_body,
                                      jnp.zeros((1, n_blk), acc_dtype))
 
         # padded split rows (s_global >= s_total) skip the FMA loop entirely
         # and store zeros, so padding costs no work and no garbage values
-        row = jax.lax.cond(s_global < s_total, compute_row,
-                           lambda: jnp.zeros((1, n_blk), acc_dtype))
-        out_ref[0, pl.dslice(s, 1), :] = row.astype(dtype)
+        rows_ref[pl.dslice(s, 1), :] = jax.lax.cond(
+            s_global < s_total, compute_row,
+            lambda: jnp.zeros((1, n_blk), acc_dtype))
         return 0
 
     jax.lax.fori_loop(0, s_block, s_body, 0)
+    # one aligned store of the whole (s_block, n_block) output block
+    out_ref[0] = rows_ref[...].astype(out_ref.dtype)
 
 
 @functools.partial(
@@ -79,8 +124,9 @@ def ema_pallas(
     *,
     s_block: int = 8,
     n_block: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
+    interpret = resolve_interpret(interpret)
     s, l = ia.shape
     batched = m_a.ndim > 2
     if m_a.ndim != y_p.ndim:
@@ -123,11 +169,11 @@ def ema_pallas(
         ],
         out_specs=pl.BlockSpec((1, s_block, n_block),
                                lambda bb, sb, nb, IA, IP: (bb, sb, nb)),
+        scratch_shapes=_scratch_shapes(m_a.shape[1], y_p.shape[1], s_block,
+                                       n_block, dtype),
     )
-    from repro.kernels.ema.ops import accum_dtype
     out = pl.pallas_call(
-        functools.partial(_kernel, s_block=s_block, l=l, s_total=s,
-                          acc_dtype=accum_dtype(dtype)),
+        functools.partial(_kernel, s_block=s_block, l=l, s_total=s),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, s_pad, n_pad), dtype),
         interpret=interpret,

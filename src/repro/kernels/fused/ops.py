@@ -26,20 +26,17 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.graph.structure import Graph
-from repro.kernels.ema.ops import (_PALLAS_VMEM_BYTES, accum_dtype, ema_xla,
-                                   pallas_supports_dtype)
-from repro.kernels.fused.pallas_fused import (batch_block_fits,
-                                              fused_spmm_ema_pallas,
+from repro.kernels import resolve_interpret
+from repro.kernels.ema.ops import ema_xla, pallas_supports_dtype
+from repro.kernels.fused.pallas_fused import (fused_spmm_ema_pallas,
                                               fused_spmm_ema_shared_pallas,
                                               group_batch_block_fits)
 from repro.obs import metrics as _metrics
 
 __all__ = ["FusedPrep", "prepare_fused", "fused_spmm_ema",
-           "fused_spmm_ema_shared", "fused_fits_vmem",
-           "fused_group_fits_vmem"]
+           "fused_spmm_ema_shared", "fused_group_fits_vmem"]
 
 
 @jax.tree_util.register_pytree_node_class
@@ -66,7 +63,8 @@ class FusedPrep:
         return int(self.arrays["blocks"].shape[0])
 
 
-def prepare_fused(g: Graph, *, tile: int = 128, interpret: bool = True,
+def prepare_fused(g: Graph, *, tile: int = 128,
+                  interpret: bool | None = None,
                   dtype=jnp.float32, reorder: str = "") -> FusedPrep:
     """BSR block stream (every dst tile populated, sorted by dst tile) plus
     the raw edge lists for the XLA fallback path. ``dtype`` is the storage
@@ -82,36 +80,20 @@ def prepare_fused(g: Graph, *, tile: int = 128, interpret: bool = True,
          "src_tile": jnp.asarray(bs.src_tile),
          "dst_tile": jnp.asarray(bs.dst_tile),
          "fb_src": jnp.asarray(src), "fb_dst": jnp.asarray(dst)},
-        {"tile": tile, "n_tiles": bs.n_tiles, "interpret": interpret,
+        {"tile": tile, "n_tiles": bs.n_tiles,
+         "interpret": resolve_interpret(interpret),
          "reorder": reorder},
     )
 
 
-def fused_fits_vmem(c_a: int, c_p: int, s: int, *, l: int = 0,
-                    tile: int = 128, dtype=jnp.float32) -> bool:
-    """VMEM residency of one fused grid step: active block + passive block +
-    y scratch + adjacency block + the (padded) output block + the resident
-    one-hot split-selection matrices (``l`` splits). Sized with the
-    accumulator itemsize — the scratch buffers run in the wider pair member
-    even when storage is bf16."""
-    itemsize = np.dtype(accum_dtype(dtype)).itemsize
-    s_pad = -(-s // 8) * 8
-    rows = c_a + 2 * c_p + tile + s_pad
-    sel = l * s_pad * (c_a + c_p)
-    return (rows * tile + sel) * itemsize < _PALLAS_VMEM_BYTES
-
-
 def fused_group_fits_vmem(c_as, c_p: int, ss, ls, *, tile: int = 128,
                           dtype=jnp.float32) -> bool:
-    """VMEM residency of one shared-passive group step: every consumer's
-    active/output blocks and selection matrices resident together, the
-    passive block and y scratch paid once. Accumulator-itemsize sized,
-    matching :func:`fused_fits_vmem`."""
-    itemsize = np.dtype(accum_dtype(dtype)).itemsize
-    s_pads = [-(-s // 8) * 8 for s in ss]
-    rows = sum(c_as) + sum(s_pads) + 2 * c_p + tile
-    sel = sum(l * sp * (ca + c_p) for l, sp, ca in zip(ls, s_pads, c_as))
-    return (rows * tile + sel) * itemsize < _PALLAS_VMEM_BYTES
+    """Whether one single-coloring step of the shared-passive group kernel
+    (a group of one is the solo kernel) fits VMEM, by the kernel's own
+    working-set model, ``pallas_fused.group_vmem_bytes``."""
+    return group_batch_block_fits(1, tuple(c_as), c_p,
+                                  tuple(-(-s // 8) * 8 for s in ss),
+                                  tuple(ls), tile, dtype)
 
 
 def _fallback(m_a, m_p, ia, ip, prep: FusedPrep) -> jnp.ndarray:
@@ -147,18 +129,11 @@ def fused_spmm_ema(m_a: jnp.ndarray, m_p: jnp.ndarray,
         _metrics.counter("kernel_fallbacks_total", kernel="fused",
                          reason="dtype_unsupported").inc()
         return _fallback(m_a, m_p, ia, ip, prep)
-    if not fused_fits_vmem(m_a.shape[-2], m_p.shape[-2], ia.shape[0],
-                           l=ia.shape[1], tile=st["tile"], dtype=dtype):
+    if not fused_group_fits_vmem((m_a.shape[-2],), m_p.shape[-2],
+                                 (ia.shape[0],), (ia.shape[1],),
+                                 tile=st["tile"], dtype=dtype):
         _metrics.counter("kernel_fallbacks_total", kernel="fused",
                          reason="vmem_overflow").inc()
-        return _fallback(m_a, m_p, ia, ip, prep)
-    s_pad = -(-ia.shape[0] // 8) * 8
-    if not batch_block_fits(1, m_a.shape[-2], m_p.shape[-2], s_pad,
-                            ia.shape[1], st["tile"],
-                            np.dtype(accum_dtype(dtype)).itemsize):
-        # even a single-coloring batch block oversubscribes VMEM
-        _metrics.counter("kernel_fallbacks_total", kernel="fused",
-                         reason="batch_block").inc()
         return _fallback(m_a, m_p, ia, ip, prep)
     _metrics.counter("kernel_launches_total", kernel="fused",
                      path="pallas").inc()
@@ -212,12 +187,8 @@ def fused_spmm_ema_shared(m_as, m_p: jnp.ndarray, ias, ips,
         _metrics.counter("kernel_fallbacks_total", kernel="fused_shared",
                          reason="dtype_unsupported").inc()
         return _fallback_shared(m_as, m_p, ias, ips, prep)
-    s_pads = tuple(-(-s // 8) * 8 for s in ss)
-    item = np.dtype(accum_dtype(dtype)).itemsize
-    if not (fused_group_fits_vmem(c_as, m_p.shape[-2], ss, ls,
-                                  tile=st["tile"], dtype=dtype)
-            and group_batch_block_fits(1, c_as, m_p.shape[-2], s_pads, ls,
-                                       st["tile"], item)):
+    if not fused_group_fits_vmem(c_as, m_p.shape[-2], ss, ls,
+                                 tile=st["tile"], dtype=dtype):
         _metrics.counter("kernel_fallbacks_total", kernel="fused_shared",
                          reason="vmem_overflow").inc()
         return _fallback_shared(m_as, m_p, ias, ips, prep)

@@ -51,32 +51,58 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
+from repro.kernels import HIGHEST, accum_dtype, resolve_interpret, vmem_bytes
+
 __all__ = ["fused_spmm_ema_pallas", "fused_spmm_ema_shared_pallas",
-           "pick_batch_block", "batch_block_fits",
-           "group_batch_block_fits", "pick_group_batch_block"]
-
-# conservative per-core VMEM working-set budget (matches ema.ops)
-_VMEM_BUDGET = 12 * 1024 * 1024
+           "group_vmem_bytes", "group_batch_block_fits",
+           "pick_group_batch_block"]
 
 
-def batch_block_fits(bb: int, c_a: int, c_p: int, s_pad: int, l: int,
-                     tile: int, itemsize: int) -> bool:
-    """Whether a ``bb``-coloring batch block's fused working set fits VMEM:
-    ``bb`` copies of the active block, the passive block, the y scratch,
-    and the output block, plus one adjacency tile and the (batch-free)
-    selection matrices."""
-    per_b = (c_a + 2 * c_p + s_pad) * tile
-    fixed = tile * tile + l * s_pad * (c_a + c_p)
-    return (bb * per_b + fixed) * itemsize < _VMEM_BUDGET
+def group_vmem_bytes(bb: int, c_as: tuple[int, ...], c_p: int,
+                     s_pads: tuple[int, ...], ls: tuple[int, ...],
+                     tile: int, dtype) -> int:
+    """VMEM working set of one grid step of the shared-passive group kernel
+    (a group of one is the solo kernel) with a ``bb``-coloring batch block.
+
+    Double-buffered block operands in the storage dtype: the adjacency
+    tile, the passive block, and per consumer its active block, output
+    block and two selection stacks. Single-buffered, in the accumulator
+    dtype: the y scratch, and the split-combination temporaries of the
+    largest consumer (accumulator, selected active rows, selected passive
+    rows, transposed result, each ``(s_pad, bb, tile)``, plus the widened
+    active block) — consumers combine one after another.
+    """
+    acc = accum_dtype(dtype)
+    total = (vmem_bytes(tile, tile, dtype, copies=2)
+             + bb * vmem_bytes(c_p, tile, dtype, copies=2)
+             + bb * vmem_bytes(c_p, tile, acc))
+    temps = 0
+    for c_a, s_pad, l in zip(c_as, s_pads, ls):
+        total += (bb * vmem_bytes(c_a, tile, dtype, copies=2)
+                  + bb * vmem_bytes(s_pad, tile, dtype, copies=2)
+                  + l * vmem_bytes(s_pad, c_a, dtype, copies=2)
+                  + l * vmem_bytes(s_pad, c_p, dtype, copies=2))
+        temps = max(temps, 4 * s_pad * vmem_bytes(bb, tile, acc)
+                    + bb * vmem_bytes(c_a, tile, acc))
+    return total + temps
 
 
-def pick_batch_block(b: int, c_a: int, c_p: int, s_pad: int, l: int,
-                     tile: int, itemsize: int) -> int:
-    """Largest batch block whose fused working set fits the VMEM budget
-    (see :func:`batch_block_fits`); floors at 1."""
+def group_batch_block_fits(bb: int, c_as: tuple[int, ...], c_p: int,
+                           s_pads: tuple[int, ...], ls: tuple[int, ...],
+                           tile: int, dtype) -> bool:
+    """Whether a ``bb``-coloring group step fits the VMEM budget."""
+    return group_vmem_bytes(bb, c_as, c_p, s_pads, ls, tile,
+                            dtype) < kernels.VMEM_BUDGET_BYTES
+
+
+def pick_group_batch_block(b: int, c_as: tuple[int, ...], c_p: int,
+                           s_pads: tuple[int, ...], ls: tuple[int, ...],
+                           tile: int, dtype) -> int:
+    """Largest batch block whose group working set fits VMEM; floors at 1."""
     bb = max(1, b)
-    while bb > 1 and not batch_block_fits(bb, c_a, c_p, s_pad, l, tile,
-                                          itemsize):
+    while bb > 1 and not group_batch_block_fits(bb, c_as, c_p, s_pads, ls,
+                                                tile, dtype):
         bb = -(-bb // 2)
     return bb
 
@@ -104,7 +130,7 @@ def _kernel(dst_tile_ref, src_tile_ref,                   # scalar prefetch
     mp_flat = mp_ref[...].reshape(bb * c_p, tile).astype(acc_dtype)
     y_ref[...] += jax.lax.dot(
         mp_flat, blocks_ref[0].astype(acc_dtype),
-        preferred_element_type=acc_dtype,
+        preferred_element_type=acc_dtype, precision=HIGHEST,
     ).reshape(bb, c_p, tile)
 
     # --- eMA leg: on the tile's last block, combine and write the output.
@@ -127,9 +153,11 @@ def _kernel(dst_tile_ref, src_tile_ref,                   # scalar prefetch
             sel_a = sela_ref[pl.dslice(i, 1)][0].astype(acc_dtype)  # (S_pad, Ca)
             sel_p = selp_ref[pl.dslice(i, 1)][0].astype(acc_dtype)  # (S_pad, Cp)
             a_rows = jax.lax.dot_general(
-                sel_a, ma, contract, preferred_element_type=acc_dtype)
+                sel_a, ma, contract, preferred_element_type=acc_dtype,
+                precision=HIGHEST)
             p_rows = jax.lax.dot_general(
-                sel_p, y_ref[...], contract, preferred_element_type=acc_dtype)
+                sel_p, y_ref[...], contract, preferred_element_type=acc_dtype,
+                precision=HIGHEST)
             return acc + a_rows * p_rows                  # (S_pad, bb, tile)
 
         acc = jax.lax.fori_loop(
@@ -151,25 +179,23 @@ def fused_spmm_ema_pallas(
     *,
     n_tiles: int,
     tile: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """-> (B, S, N): ``ema(m_a, m_p @ A, ia, ip)`` without materializing
     the ``(B, Cp, N)`` neighbor-sum table. Inputs must be 3-D (batched);
     the ops-layer wrapper handles rank/padding/dtype dispatch."""
+    interpret = resolve_interpret(interpret)
     s, l = ia.shape
     b, _, n = m_a.shape
     assert n == n_tiles * tile, (n, n_tiles, tile)
     assert m_p.shape[0] == b and m_p.shape[2] == n
-    from repro.kernels.ema.ops import accum_dtype
     dtype = jnp.promote_types(m_a.dtype, m_p.dtype)
     acc_dt = jnp.dtype(accum_dtype(dtype))
     m_a = m_a.astype(dtype)
     m_p = m_p.astype(dtype)
     c_a, c_p = m_a.shape[1], m_p.shape[1]
     s_pad = -(-s // 8) * 8          # sublane multiple for the output block
-    # fit check uses the accumulator itemsize: the y scratch and fori
-    # accumulator dominate the working set and live in the wider dtype
-    bb = pick_batch_block(b, c_a, c_p, s_pad, l, tile, acc_dt.itemsize)
+    bb = pick_group_batch_block(b, (c_a,), c_p, (s_pad,), (l,), tile, dtype)
     b_pad = -(-b // bb) * bb
     if b_pad != b:
         m_a = jnp.pad(m_a, ((0, b_pad - b), (0, 0), (0, 0)))
@@ -219,29 +245,6 @@ def fused_spmm_ema_pallas(
 # Shared-passive group launch: one SpMM leg, many consumers
 # ---------------------------------------------------------------------------
 
-def group_batch_block_fits(bb: int, c_as: tuple[int, ...], c_p: int,
-                           s_pads: tuple[int, ...], ls: tuple[int, ...],
-                           tile: int, itemsize: int) -> bool:
-    """VMEM fit for a shared-passive group step: every consumer's active and
-    output blocks are resident simultaneously, but the passive block and the
-    y scratch are paid ONCE for the whole group."""
-    per_b = (sum(c_as) + sum(s_pads) + 2 * c_p) * tile
-    fixed = tile * tile + sum(
-        l * sp * (ca + c_p) for l, sp, ca in zip(ls, s_pads, c_as))
-    return (bb * per_b + fixed) * itemsize < _VMEM_BUDGET
-
-
-def pick_group_batch_block(b: int, c_as: tuple[int, ...], c_p: int,
-                           s_pads: tuple[int, ...], ls: tuple[int, ...],
-                           tile: int, itemsize: int) -> int:
-    """Largest batch block whose group working set fits VMEM; floors at 1."""
-    bb = max(1, b)
-    while bb > 1 and not group_batch_block_fits(bb, c_as, c_p, s_pads, ls,
-                                                tile, itemsize):
-        bb = -(-bb // 2)
-    return bb
-
-
 def _shared_kernel(dst_tile_ref, src_tile_ref,            # scalar prefetch
                    *refs, n_cons: int, ls: tuple[int, ...]):
     # refs layout: blocks, mp, (ma_i, sela_i, selp_i) x n_cons,
@@ -266,7 +269,7 @@ def _shared_kernel(dst_tile_ref, src_tile_ref,            # scalar prefetch
     mp_flat = mp_ref[...].reshape(bb * c_p, tile).astype(acc_dtype)
     y_ref[...] += jax.lax.dot(
         mp_flat, blocks_ref[0].astype(acc_dtype),
-        preferred_element_type=acc_dtype,
+        preferred_element_type=acc_dtype, precision=HIGHEST,
     ).reshape(bb, c_p, tile)
 
     is_last = jnp.logical_or(
@@ -288,10 +291,11 @@ def _shared_kernel(dst_tile_ref, src_tile_ref,            # scalar prefetch
                 sel_a = sela_ref[pl.dslice(i, 1)][0].astype(acc_dtype)
                 sel_p = selp_ref[pl.dslice(i, 1)][0].astype(acc_dtype)
                 a_rows = jax.lax.dot_general(
-                    sel_a, ma, contract, preferred_element_type=acc_dtype)
+                    sel_a, ma, contract, preferred_element_type=acc_dtype,
+                precision=HIGHEST)
                 p_rows = jax.lax.dot_general(
                     sel_p, y_ref[...], contract,
-                    preferred_element_type=acc_dtype)
+                    preferred_element_type=acc_dtype, precision=HIGHEST)
                 return acc + a_rows * p_rows
 
             acc = jax.lax.fori_loop(
@@ -313,12 +317,12 @@ def fused_spmm_ema_shared_pallas(
     *,
     n_tiles: int,
     tile: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple:
     """-> per-consumer (B, S_i, N) tuple: every consumer's
     ``ema(m_a_i, m_p @ A, ia_i, ip_i)`` from ONE launch whose SpMM leg runs
     once into shared VMEM scratch. Inputs must be 3-D (batched)."""
-    from repro.kernels.ema.ops import accum_dtype
+    interpret = resolve_interpret(interpret)
     n_cons = len(m_as)
     assert n_cons == len(ias) == len(ips) and n_cons >= 1
     b, _, n = m_as[0].shape
@@ -335,8 +339,7 @@ def fused_spmm_ema_shared_pallas(
     ss = tuple(ia.shape[0] for ia in ias)
     ls = tuple(ia.shape[1] for ia in ias)
     s_pads = tuple(-(-s // 8) * 8 for s in ss)
-    bb = pick_group_batch_block(b, c_as, c_p, s_pads, ls, tile,
-                                acc_dt.itemsize)
+    bb = pick_group_batch_block(b, c_as, c_p, s_pads, ls, tile, dtype)
     b_pad = -(-b // bb) * bb
     if b_pad != b:
         pad = ((0, b_pad - b), (0, 0), (0, 0))

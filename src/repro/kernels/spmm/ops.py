@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.graph.structure import Graph
+from repro.kernels import resolve_interpret
 from repro.kernels import autotune as _autotune
 from repro.kernels.ema import ops as ema_ops
 from repro.kernels.spmm.pallas_bsr import spmm_bsr_pallas
@@ -58,7 +59,7 @@ class SpmmPrep:
 
 
 def prepare(g: Graph, method: str = "segment", *, tile: int = 128,
-            chunk_size: int = 512, interpret: bool = True,
+            chunk_size: int = 512, interpret: bool | None = None,
             dtype=jnp.float32, reorder: str = "") -> SpmmPrep:
     """``dtype`` is the table *storage* dtype: the Pallas backends store
     their adjacency operand (dense BSR blocks / gather masks) in it, so a
@@ -69,6 +70,7 @@ def prepare(g: Graph, method: str = "segment", *, tile: int = 128,
     streams with different locality."""
     if method not in METHODS:
         raise ValueError(f"unknown spmm method {method!r}")
+    interpret = resolve_interpret(interpret)
     if method == "segment":
         src, dst = g.edges_by_dst
         return SpmmPrep(method, g.n,

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import HIGHEST, accum_dtype, resolve_interpret
+
 __all__ = ["spmm_bsr_pallas"]
 
 
@@ -41,7 +43,7 @@ def _kernel(src_tile_ref, dst_tile_ref, blocks_ref, m_ref, out_ref, acc_ref):
     # the output block is written once, on the tile's last block
     acc_ref[...] += jax.lax.dot(
         m_ref[...].astype(acc_dtype), blocks_ref[0].astype(acc_dtype),
-        preferred_element_type=acc_dtype,
+        preferred_element_type=acc_dtype, precision=HIGHEST,
     )
 
     is_last = jnp.logical_or(
@@ -65,8 +67,9 @@ def spmm_bsr_pallas(
     n_tiles: int,
     tile: int = 128,
     c_block: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
+    interpret = resolve_interpret(interpret)
     c, n = m.shape
     assert n == n_tiles * tile, (n, n_tiles, tile)
     dtype = m.dtype
@@ -76,7 +79,6 @@ def spmm_bsr_pallas(
         m = jnp.pad(m, ((0, c_pad - c), (0, 0)))
     n_blocks = blocks.shape[0]
 
-    from repro.kernels.ema.ops import accum_dtype
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(c_pad // c_block, n_blocks),

@@ -30,7 +30,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import HIGHEST, accum_dtype, resolve_interpret
+
 __all__ = ["spmm_gather_pallas"]
+
+# edge chunks per block of the src / dst_local / mask arrays
+_CHUNK_ROWS = 8
 
 
 def _kernel(src_tile_ref, dst_tile_ref,        # scalar prefetch (SMEM)
@@ -51,24 +56,26 @@ def _kernel(src_tile_ref, dst_tile_ref,        # scalar prefetch (SMEM)
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    src = src_ref[0, :]            # (E,) global src ids of this chunk
-    dstl = dstl_ref[0, :]          # (E,) local dst offsets
-    mask = mask_ref[0, :].astype(acc_dtype)  # (E,) {0,1}
+    # this chunk's row of the 8-chunk edge block
+    row = pl.dslice(t % _CHUNK_ROWS, 1)
+    src = src_ref[row, :]          # (1, E) global src ids of this chunk
+    dstl = dstl_ref[row, :]        # (1, E) local dst offsets
+    mask = mask_ref[row, :].astype(acc_dtype)  # (1, E) {0,1}
 
     src_local = src - src_tile_ref[t] * tile
-    lane = jax.lax.broadcasted_iota(jnp.int32, (tile, src.shape[0]), 0)
-    onehot_src = jnp.where(lane == src_local[None, :], mask[None, :],
-                           jnp.zeros((), acc_dtype))
-    onehot_dst = (lane == dstl[None, :]).astype(acc_dtype)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (tile, src.shape[1]), 0)
+    onehot_src = jnp.where(lane == src_local, mask, jnp.zeros((), acc_dtype))
+    onehot_dst = (lane == dstl).astype(acc_dtype)
     p = jax.lax.dot_general(
         onehot_src, onehot_dst,
         (((1,), (1,)), ((), ())),
-        preferred_element_type=acc_dtype,
+        preferred_element_type=acc_dtype, precision=HIGHEST,
     )                               # (T, T) densified adjacency block
     # partial sums in the accumulator dtype (f32 for bf16 storage); the
     # output block is written once, on the tile's last chunk
     acc_ref[...] += jax.lax.dot(
-        m_ref[...].astype(acc_dtype), p, preferred_element_type=acc_dtype
+        m_ref[...].astype(acc_dtype), p, preferred_element_type=acc_dtype,
+        precision=HIGHEST
     )
 
     is_last = jnp.logical_or(
@@ -95,25 +102,35 @@ def spmm_gather_pallas(
     n_tiles: int,
     tile: int = 128,
     c_block: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
-    from repro.kernels.ema.ops import accum_dtype
+    interpret = resolve_interpret(interpret)
     c, n = m.shape
     assert n == n_tiles * tile, (n, n_tiles, tile)
     dtype = m.dtype
-    mask = mask.astype(dtype)
     c_pad = -(-c // c_block) * c_block
     if c_pad != c:
         m = jnp.pad(m, ((0, c_pad - c), (0, 0)))
-    n_chunks = src.shape[0]
+    n_chunks, e = src.shape
+    # Mosaic blocks span 8 rows at least, so the edge arrays are read 8
+    # chunks per block (one DMA per 8 grid steps) and the kernel picks its
+    # chunk's row; the mask rides in the 32-bit accumulator dtype, whose
+    # single rows load at any offset
+    r_pad = -(-n_chunks // _CHUNK_ROWS) * _CHUNK_ROWS - n_chunks
+    src, dst_local, mask = (
+        jnp.pad(x, ((0, r_pad), (0, 0))) for x in
+        (src, dst_local, mask.astype(accum_dtype(dtype))))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(c_pad // c_block, n_chunks),
         in_specs=[
-            pl.BlockSpec((1, src.shape[1]), lambda cb, t, st, dt: (t, 0)),
-            pl.BlockSpec((1, src.shape[1]), lambda cb, t, st, dt: (t, 0)),
-            pl.BlockSpec((1, src.shape[1]), lambda cb, t, st, dt: (t, 0)),
+            pl.BlockSpec((_CHUNK_ROWS, e),
+                         lambda cb, t, st, dt: (t // _CHUNK_ROWS, 0)),
+            pl.BlockSpec((_CHUNK_ROWS, e),
+                         lambda cb, t, st, dt: (t // _CHUNK_ROWS, 0)),
+            pl.BlockSpec((_CHUNK_ROWS, e),
+                         lambda cb, t, st, dt: (t // _CHUNK_ROWS, 0)),
             pl.BlockSpec((c_block, tile), lambda cb, t, st, dt: (cb, st[t])),
         ],
         out_specs=pl.BlockSpec((c_block, tile), lambda cb, t, st, dt: (cb, dt[t])),

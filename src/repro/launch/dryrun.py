@@ -1,8 +1,7 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture x input shape) on
 the production meshes, record memory/cost analysis + collective bytes.
+The meshes are 512 host devices of the CPU backend, which ``main`` asks
+for before the backend starts.
 
     PYTHONPATH=src python -m repro.launch.dryrun --arch llama3-8b \
         --cell train_4k --mesh single --out results/dryrun
@@ -13,6 +12,7 @@ One JSON per (arch, cell, mesh) so independent processes can split the grid.
 
 import argparse
 import json
+import os
 import sys
 import time
 import traceback
@@ -232,6 +232,12 @@ def main(argv=None):
     ap.add_argument("--out", default="results/dryrun")
     ap.add_argument("--skip-existing", action="store_true")
     args = ap.parse_args(argv)
+    # fake host devices exist on the CPU backend only; the flag is added
+    # to the caller's XLA_FLAGS, which must not have started a backend yet
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " "
+                               "--xla_force_host_platform_device_count=512"
+                               ).strip()
+    jax.config.update("jax_platforms", "cpu")
 
     archs = list(ARCH_IDS) + ["pgbsc"] if args.arch == "all" \
         else args.arch.split(",")

@@ -13,13 +13,11 @@ import jax
 __all__ = ["make_production_mesh", "make_mesh"]
 
 
-def make_mesh(shape, axes):
-    # jax.sharding.AxisType landed after 0.4.x; Auto is the implicit default
-    # there, so omitting axis_types is semantics-preserving on old versions.
-    kw = {}
-    if hasattr(jax.sharding, "AxisType"):
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, **kw)
+def make_mesh(shape, axes, devices=None):
+    """A mesh of Auto axes over ``devices`` (default: all of them)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

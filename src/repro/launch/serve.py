@@ -45,6 +45,7 @@ import json
 
 from repro.core.templates import TemplateSpec
 from repro.graph import erdos_renyi, rmat
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import metrics as obs_metrics
 from repro.obs import tracing as obs_tracing
 from repro.obs.validate import validate_snapshot
@@ -52,7 +53,7 @@ from repro.service import CountingService, CountRequest
 from repro.service.cache import DEFAULT_MAX_ENTRIES, EngineCache
 
 
-def _load_graph(spec: str, edge_list: str | None):
+def load_graph(spec: str, edge_list: str | None):
     if edge_list:
         from repro.graph.io import load_cached
         return load_cached(edge_list)
@@ -72,14 +73,32 @@ def _retry_policy(args):
         timeout_s=args.dispatch_timeout if args.dispatch_timeout else None)
 
 
-def _serve_http(args, g, budget, engine_kw) -> int:
-    """Serving mode: async QoS service + HTTP front end until SIGINT."""
-    import signal
-    import threading
+def engine_options(args) -> tuple[int | None, dict]:
+    """(memory budget in bytes, engine build options) from the CLI flags."""
+    budget = None if args.memory_budget_mb is None \
+        else int(args.memory_budget_mb * 2 ** 20)
+    engine_kw = {}
+    if args.fuse:
+        engine_kw["fuse_spmm_ema"] = True
+    if args.reorder:
+        engine_kw["reorder"] = args.reorder
+    if args.dtype:
+        import jax.numpy as jnp
+        engine_kw["dtype"] = getattr(jnp, args.dtype)
+    return budget, engine_kw
 
+
+def start_http_service(args, g):
+    """Build the async QoS service for graph ``g`` (registered as ``"g"``)
+    from the parsed CLI flags, queue the advertised templates for the warm
+    pool, and start the dispatcher and the HTTP front end on daemon
+    threads. Returns ``(service, http server)``; stop with
+    ``server.shutdown()`` then ``service.close()``. ``serve --http`` runs
+    this, and so does any in-process driver of the served path."""
     from repro.service import AsyncCountingService
     from repro.service.frontend import serve_forever
 
+    budget, engine_kw = engine_options(args)
     svc = AsyncCountingService(
         ledger_root=args.ledger, round_size=args.round_size,
         default_max_iters=args.iters, batch_size=args.batch_size,
@@ -98,7 +117,15 @@ def _serve_http(args, g, budget, engine_kw) -> int:
     for i, es in enumerate(args.template_edges):
         svc.prewarm("g", TemplateSpec.from_edge_string(es, name=f"edges{i}"),
                     args.engine, args.plan)
-    httpd = serve_forever(svc, host=args.host, port=args.http)
+    return svc, serve_forever(svc, host=args.host, port=args.http)
+
+
+def _serve_http(args, g) -> int:
+    """Serving mode: async QoS service + HTTP front end until SIGINT."""
+    import signal
+    import threading
+
+    svc, httpd = start_http_service(args, g)
     host, port = httpd.server_address[:2]
     print(f"serving HTTP on {host}:{port} (graph 'g', queue depth "
           f"{args.queue_depth}); POST /count, GET /result/<id>, "
@@ -121,7 +148,7 @@ def _serve_http(args, g, budget, engine_kw) -> int:
     return 0
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--graph", default="rmat:12")
     ap.add_argument("--edge-list", default=None)
@@ -197,13 +224,19 @@ def main(argv=None):
                     help="seed for the deterministic fault schedule")
     ap.add_argument("--dispatch-timeout", type=float, default=120.0,
                     metavar="S",
-                    help="wall-clock watchdog per device dispatch; a hung "
-                         "dispatch is abandoned and retried (0 = off)")
+                    help="wall-clock watchdog slack per device dispatch, "
+                         "on top of its modeled SpMM time; a hung dispatch "
+                         "is abandoned and retried (0 = off)")
     ap.add_argument("--dispatch-retries", type=int, default=4,
                     metavar="N",
                     help="retry budget per dispatch (jittered exponential "
                          "backoff between attempts)")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    enable_compile_cache()
 
     if args.trace:
         obs_tracing.configure(enabled=True, sync=True)
@@ -216,22 +249,13 @@ def main(argv=None):
         print(f"fault injection armed: {len(plan.specs)} spec(s), "
               f"seed {args.inject_seed}", flush=True)
 
-    g = _load_graph(args.graph, args.edge_list)
+    g = load_graph(args.graph, args.edge_list)
     print(f"serving graph: n={g.n} edge-slots={g.m} "
           f"avg_deg={g.avg_degree:.1f} fingerprint={g.fingerprint[:12]}")
 
-    budget = None if args.memory_budget_mb is None \
-        else int(args.memory_budget_mb * 2 ** 20)
-    engine_kw = {}
-    if args.fuse:
-        engine_kw["fuse_spmm_ema"] = True
-    if args.reorder:
-        engine_kw["reorder"] = args.reorder
-    if args.dtype:
-        import jax.numpy as jnp
-        engine_kw["dtype"] = getattr(jnp, args.dtype)
     if args.http is not None:
-        return _serve_http(args, g, budget, engine_kw)
+        return _serve_http(args, g)
+    budget, engine_kw = engine_options(args)
     svc = CountingService(
         ledger_root=args.ledger, round_size=args.round_size,
         default_max_iters=args.iters, batch_size=args.batch_size,

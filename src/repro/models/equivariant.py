@@ -25,11 +25,12 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 __all__ = ["init_nequip", "nequip_forward", "nequip_energy_loss"]
 
 _EPS = 1e-9
-_I3 = jnp.eye(3)
+_I3 = np.eye(3, dtype=np.float32)   # a host constant: no device at import
 
 
 # ---------------------------------------------------------------- tensor ops

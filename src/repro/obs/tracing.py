@@ -215,22 +215,18 @@ def arm_profiler(trace_dir: str | None) -> None:
 
 @contextlib.contextmanager
 def profiled_dispatch():
-    """Wrap one dispatch; emits a jax profiler trace if one is armed."""
+    """Wrap one dispatch; emits a jax profiler trace if one is armed.
+
+    A profiler that fails to start raises here: a run that asked for a
+    trace and silently ran untraced would look like a traced one."""
     d = _profile_dir[0]
     if d is None:
         yield
         return
     _profile_dir[0] = None     # one-shot: disarm before running
-    try:
-        import jax.profiler as prof
-        prof.start_trace(d)
-    except Exception:
-        yield
-        return
+    import jax.profiler as prof
+    prof.start_trace(d)
     try:
         yield
     finally:
-        try:
-            prof.stop_trace()
-        except Exception:
-            pass
+        prof.stop_trace()

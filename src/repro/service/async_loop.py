@@ -380,8 +380,8 @@ class AsyncCountingService(CountingService):
         if g is None:
             return
         try:
-            self.engine_cache.get(g, template, engine, plan,
-                                  **self.engine_kw)
+            self._warm(self.engine_cache.get(g, template, engine, plan,
+                                             **self.engine_kw))
             _metrics.counter("service_warm_builds_total").inc()
         except Exception:
             _metrics.counter("service_warm_failures_total").inc()

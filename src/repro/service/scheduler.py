@@ -46,7 +46,13 @@ from repro.service.cache import EngineCache, EstimateCache
 from repro.service.requests import (CountRequest, RequestResult,
                                     RequestStatus, RunningStat)
 
-__all__ = ["CountingService"]
+__all__ = ["CountingService", "WATCHDOG_FLOOR_EDGE_ROWS_PER_S"]
+
+# Slowest SpMM throughput (edge slots x table rows per second) a healthy
+# dispatch may run at before the watchdog takes it for hung. The XLA segment
+# SpMM ran at about 61M on a TPU v5e at Graph500 RMAT scale 20 (PERF.md); a
+# quarter of that leaves room for host jitter and slower paths.
+WATCHDOG_FLOOR_EDGE_ROWS_PER_S = 16e6
 
 
 @dataclasses.dataclass
@@ -133,8 +139,9 @@ class CountingService:
         Dispatch-path containment (:class:`~repro.resilience.retry.
         RetryPolicy`): per-dispatch retry budget, jittered exponential
         backoff, and (when ``timeout_s`` is set) a wall-clock watchdog
-        that abandons hung dispatches. None = the default policy (4
-        attempts, no watchdog).
+        that abandons hung dispatches. ``timeout_s`` is the slack on top
+        of the dispatch's modeled SpMM time (:meth:`dispatch_watchdog_s`).
+        None = the default policy (4 attempts, no watchdog).
     degrade_after / degrade_cooldown_s:
         Degradation-ladder shape: consecutive failures per step-down, and
         the failure-free interval before a one-rung re-promotion.
@@ -290,11 +297,34 @@ class CountingService:
         eng = self.engine_cache.get(g, grp.spec, grp.engine_name,
                                     grp.plan_name,
                                     **ladder.apply(self.engine_kw))
+        self._warm(eng)
         grp.engine = eng
         grp.runner.counter = engine_counter(
             eng, seed=grp.seed, batch_size=self.batch_size, label=grp.label)
         _metrics.counter("engine_rebuilds_total",
                          level=ladder.level_name).inc()
+
+    def _warm(self, eng) -> None:
+        """Compile ``eng``'s dispatch program at the width a steady round
+        dispatches (``min(round_size, checkpoint_every)`` ids per runner
+        batch), so the compile lands in build time and the dispatch
+        watchdog times execution only. A shorter tail round is another
+        width and compiles at its first dispatch."""
+        eng.warm(min(self.round_size, self.checkpoint_every),
+                 batch_size=self.batch_size)
+
+    def dispatch_watchdog_s(self, grp: _Group, n_ids: int) -> float | None:
+        """Wall-clock watchdog of one dispatch of ``n_ids`` colorings: the
+        policy's ``timeout_s`` on top of the dispatch's SpMM work (edge
+        slots x table rows) at :data:`WATCHDOG_FLOOR_EDGE_ROWS_PER_S`, so
+        a large graph's healthy dispatch is not taken for a hung one.
+        None when the policy has no watchdog."""
+        slack = self.retry_policy.timeout_s
+        if slack is None:
+            return None
+        eng = grp.engine
+        rows = getattr(eng, "spmm_cols_per_coloring", 0) * n_ids
+        return slack + rows * eng.g.m / WATCHDOG_FLOOR_EDGE_ROWS_PER_S
 
     def resilience_state(self) -> dict:
         """Degradation-ladder and circuit-breaker state (``/healthz``)."""
@@ -329,6 +359,7 @@ class CountingService:
                 eng = self.engine_cache.get(
                     g, spec, st.request.engine,
                     st.request.plan, **ladder.apply(self.engine_kw))
+                self._warm(eng)
                 break
             except Exception:
                 if not ladder.on_failure(reason="build_error"):
@@ -466,11 +497,12 @@ class CountingService:
            (:class:`CircuitOpen`), no device work, no retries;
         2. **re-promotion** — a degraded ladder past its cooldown steps up
            one rung and the engine is rebuilt at the better level;
-        3. **watchdog + retry** — each attempt runs under the policy's
-           wall-clock timeout (hung dispatches are abandoned, not joined
-           forever); failures step the ladder (rebuilding the engine at
-           the degraded level) and back off with seeded jitter until the
-           attempt budget is exhausted.
+        3. **watchdog + retry** — each attempt runs under a wall-clock
+           timeout scaled to its work (:meth:`dispatch_watchdog_s`; hung
+           dispatches are abandoned, not joined forever); failures step
+           the ladder (rebuilding the engine at the degraded level) and
+           back off with seeded jitter until the attempt budget is
+           exhausted.
 
         Because samples are pure functions of ``(seed, iteration id)``, a
         retried or degraded dispatch reproduces bitwise-identical
@@ -499,32 +531,36 @@ class CountingService:
 
         per = None
         last_exc: BaseException | None = None
-        for attempt in range(1, policy.max_attempts + 1):
-            t_disp = time.perf_counter()
-            try:
-                with _tracing.span("service.dispatch",
-                                   group=grp.graph_name,
-                                   engine=grp.key[2], n=len(ids),
-                                   tenants=len(self._live_members(grp)),
-                                   attempt=attempt):
-                    with _tracing.profiled_dispatch():
-                        per = run_with_timeout(attempt_fn, policy.timeout_s,
-                                               name=grp.label)
-                break
-            except Exception as exc:
-                last_exc = exc
-                reason = "timeout" if isinstance(exc, DispatchTimeout) \
-                    else "error"
-                if ladder.on_failure(reason=f"dispatch_{reason}"):
-                    try:
-                        self._rebuild_group_engine(grp, ladder)
-                    except Exception:
-                        pass        # keep the old engine; retry may still work
-                if attempt >= policy.max_attempts:
+        # an armed profiler traces this dispatch, retries included; one
+        # that cannot start raises out of the round instead of tracing
+        # nothing
+        with _tracing.profiled_dispatch():
+            for attempt in range(1, policy.max_attempts + 1):
+                t_disp = time.perf_counter()
+                try:
+                    with _tracing.span("service.dispatch",
+                                       group=grp.graph_name,
+                                       engine=grp.key[2], n=len(ids),
+                                       tenants=len(self._live_members(grp)),
+                                       attempt=attempt):
+                        per = run_with_timeout(
+                            attempt_fn, self.dispatch_watchdog_s(grp, len(ids)),
+                            name=grp.label)
                     break
-                _metrics.counter("dispatch_retries_total",
-                                 reason=reason).inc()
-                time.sleep(policy.delay(attempt, self._retry_rng))
+                except Exception as exc:
+                    last_exc = exc
+                    reason = "timeout" if isinstance(exc, DispatchTimeout) \
+                        else "error"
+                    if ladder.on_failure(reason=f"dispatch_{reason}"):
+                        try:
+                            self._rebuild_group_engine(grp, ladder)
+                        except Exception:
+                            pass    # keep the old engine; retry may still work
+                    if attempt >= policy.max_attempts:
+                        break
+                    _metrics.counter("dispatch_retries_total",
+                                     reason=reason).inc()
+                    time.sleep(policy.delay(attempt, self._retry_rng))
         if per is None:
             breaker.on_failure()
             for m in self._live_members(grp):

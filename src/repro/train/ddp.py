@@ -13,7 +13,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
@@ -68,12 +67,12 @@ def build_ddp_step(arch: ArchConfig, mesh: Mesh,
     def step(state, batch):
         state_specs = jax.tree_util.tree_map(lambda _: P(), state)
         bspecs = batch_specs(batch)
-        fn = shard_map(
+        fn = jax.shard_map(
             local_step, mesh=mesh,
             in_specs=(state_specs, bspecs),
             out_specs=(state_specs,
                        {"grad_norm": P(), "lr": P(), "loss": P()}),
-            check_rep=False)
+            check_vma=False)
         return fn(state, batch)
 
     return step

@@ -17,6 +17,7 @@ import json
 import os
 import threading
 import time
+import types
 import urllib.error
 import urllib.request
 
@@ -34,7 +35,8 @@ from repro.service.async_loop import (TERMINAL_STATUSES,
                                       AsyncCountingService)
 from repro.service.cache import EstimateCache
 from repro.service.requests import CountRequest, RequestStatus
-from repro.service.scheduler import CountingService
+from repro.service.scheduler import (WATCHDOG_FLOOR_EDGE_ROWS_PER_S,
+                                     CountingService)
 
 
 @pytest.fixture(autouse=True)
@@ -414,6 +416,28 @@ class TestSchedulerChaos:
         assert st.status is RequestStatus.DONE
         assert st.result.estimate == base
         assert time.monotonic() - t0 < 5.0       # did not sit out the hang
+
+    def test_watchdog_scales_with_dispatch_work(self, tmp_path, graph):
+        """The policy's timeout is slack on top of the dispatch's modeled
+        SpMM time, so a big graph's healthy dispatch is not taken for hung:
+        at Graph500 RMAT scale 20 a u7 dispatch of 8 colorings runs for
+        minutes on a TPU v5e, past the 120 s serve default."""
+        svc = _service(tmp_path / "w", retry_policy=RetryPolicy(
+            max_attempts=1, timeout_s=120.0))
+        _run_one(svc, graph, template="u5")
+        (grp,) = svc._groups.values()
+        rows = grp.engine.spmm_cols_per_coloring
+        assert rows > 0
+        assert svc.dispatch_watchdog_s(grp, 8) == pytest.approx(
+            120.0 + 8 * rows * graph.m / WATCHDOG_FLOOR_EDGE_ROWS_PER_S)
+        # u7 (42 SpMM rows per coloring) on RMAT-20's 31.4M edge slots:
+        # the watchdog stands well past the 172 s such a dispatch took
+        rmat20_u7 = types.SimpleNamespace(engine=types.SimpleNamespace(
+            spmm_cols_per_coloring=42, g=types.SimpleNamespace(m=31_400_000)))
+        assert svc.dispatch_watchdog_s(rmat20_u7, 8) > 3 * 172.0
+        assert svc.dispatch_watchdog_s(grp, 0) == 120.0
+        no_dog = _service(tmp_path / "off")
+        assert no_dog.dispatch_watchdog_s(grp, 8) is None
 
     def test_unaffected_group_untouched_by_scoped_chaos(self, tmp_path,
                                                         graph):

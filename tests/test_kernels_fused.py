@@ -18,11 +18,12 @@ from repro.core import build_engine, executor as pexec, get_template
 from repro.core.colorsets import split_tables
 from repro.graph import Graph, erdos_renyi, grid_2d, rmat, star
 from repro.graph.coloring import coloring_numpy
+from repro import kernels
 from repro.kernels import autotune
 from repro.kernels.ema.ops import ema_xla
-from repro.kernels.fused import (fused_fits_vmem, fused_spmm_ema,
+from repro.kernels.fused import (fused_group_fits_vmem, fused_spmm_ema,
                                  fused_spmm_ema_shared, prepare_fused)
-from repro.kernels.fused.pallas_fused import pick_batch_block
+from repro.kernels.fused.pallas_fused import pick_group_batch_block
 from repro.kernels.spmm.ref import spmm_dense
 
 
@@ -88,15 +89,15 @@ class TestFusedKernel:
 
     def test_batch_blocking_smaller_than_batch(self, monkeypatch):
         # force bb < B so the grid walks multiple batch blocks
-        from repro.kernels.fused import pallas_fused
-        monkeypatch.setattr(pallas_fused, "_VMEM_BUDGET", 1 << 16)
+        monkeypatch.setattr(kernels, "VMEM_BUDGET_BYTES", 1 << 16)
         g = GRAPHS["rmat"]()
         ia, ip = split_tables(5, 3, 1)
         ia, ip = jnp.asarray(ia), jnp.asarray(ip)
         rng = np.random.default_rng(9)
         m_a = _rand_table(rng, (4, 5, g.n))
         m_p = _rand_table(rng, (4, 10, g.n))
-        assert pick_batch_block(4, 5, 10, 16, ia.shape[1], 128, 4) < 4
+        assert pick_group_batch_block(4, (5,), 10, (16,), (ia.shape[1],),
+                                      128, jnp.float32) < 4
         got = fused_spmm_ema(m_a, m_p, ia, ip, prepare_fused(g))
         for i in range(4):
             want = _oracle(g, m_a[i], m_p[i], ia, ip)
@@ -132,11 +133,11 @@ class TestFusedKernel:
                                    np.asarray(want), rtol=1e-3)
 
     def test_vmem_overflow_falls_back(self, monkeypatch):
-        assert not fused_fits_vmem(4000, 4000, 8000, l=100)
+        assert not fused_group_fits_vmem((4000,), 4000, (8000,), (100,))
         # shrink the budget so dispatch takes the XLA fallback, and verify
         # the kernel is really bypassed (it would raise if called)
         from repro.kernels.fused import ops as fops
-        monkeypatch.setattr(fops, "_PALLAS_VMEM_BYTES", 1)
+        monkeypatch.setattr(kernels, "VMEM_BUDGET_BYTES", 1)
         monkeypatch.setattr(
             fops, "fused_spmm_ema_pallas",
             lambda *a, **k: (_ for _ in ()).throw(
@@ -328,8 +329,7 @@ class TestSharedPassiveKernel:
             assert rel.max() <= 1e-2
 
     def test_vmem_overflow_falls_back_exactly(self, monkeypatch):
-        from repro.kernels.fused import ops as fops
-        monkeypatch.setattr(fops, "_PALLAS_VMEM_BYTES", 1 << 12)
+        monkeypatch.setattr(kernels, "VMEM_BUDGET_BYTES", 1 << 12)
         g = GRAPHS["er_uneven"]()
         m_as, m_p, ias, ips = self._inputs(g)
         outs = fused_spmm_ema_shared(m_as, m_p, ias, ips, prepare_fused(g))

@@ -69,7 +69,6 @@ class TestCompression:
 
     def test_compressed_psum_with_error_feedback(self):
         # single-device shard_map still exercises the psum path
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.launch.mesh import make_mesh
         from repro.optim.optimizer import compressed_psum
@@ -81,8 +80,9 @@ class TestCompression:
         def f(g, r):
             return compressed_psum(g, "d", r)
 
-        out, res = shard_map(f, mesh=mesh, in_specs=(P(), P()),
-                             out_specs=(P(), P()), check_rep=False)(g, r)
+        out, res = jax.shard_map(f, mesh=mesh, in_specs=(P(), P()),
+                                 out_specs=(P(), P()),
+                                 check_vma=False)(g, r)
         # sum over 1 device == dequantized value; error feedback bounded
         np.testing.assert_allclose(np.asarray(out["w"]), np.asarray(g["w"]),
                                    atol=0.05)
@@ -91,16 +91,15 @@ class TestCompression:
     def test_error_feedback_converges_over_steps(self):
         # repeated compression of a CONSTANT gradient: error feedback makes
         # the time-average exact
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.launch.mesh import make_mesh
         from repro.optim.optimizer import compressed_psum
         mesh = make_mesh((1,), ("d",))
         g = {"w": jnp.asarray([0.3, -0.7, 1.234, 0.001])}
         r = {"w": jnp.zeros((4,))}
-        f = shard_map(lambda g, r: compressed_psum(g, "d", r), mesh=mesh,
-                      in_specs=(P(), P()), out_specs=(P(), P()),
-                      check_rep=False)
+        f = jax.shard_map(lambda g, r: compressed_psum(g, "d", r),
+                          mesh=mesh, in_specs=(P(), P()),
+                          out_specs=(P(), P()), check_vma=False)
         acc = np.zeros(4)
         for t in range(50):
             out, r = f(g, r)
