@@ -741,8 +741,9 @@ class CountingEngine:
 
         A leading batch dimension on ``colors`` broadcasts straight through.
         """
-        return (jnp.arange(self.k, dtype=colors.dtype)[:, None]
-                == colors[..., None, :]).astype(self.dtype)
+        with _tracing.device_scope(_tracing.KERNEL_LEAF):
+            return (jnp.arange(self.k, dtype=colors.dtype)[:, None]
+                    == colors[..., None, :]).astype(self.dtype)
 
     def _build_pgbsc(self) -> Callable:
         splits, packs = self._splits, self._chunk_packs
@@ -801,14 +802,16 @@ class CountingEngine:
                               combine_group=partial(combine_group, ops),
                               on_step=self._peak_probe,
                               outputs=self.roots)
-            if not self.fused:
-                root = outs[0]
-                return root.astype(acc_dt).sum(axis=(-2, -1)), root
-            # one fused walk, one (..., T) totals vector — template j's
-            # entry comes from its own root table
-            totals = jnp.stack(
-                [r.astype(acc_dt).sum(axis=(-2, -1)) for r in outs], axis=-1)
-            return totals, outs
+            with _tracing.device_scope(_tracing.KERNEL_ROOT):
+                if not self.fused:
+                    root = outs[0]
+                    return root.astype(acc_dt).sum(axis=(-2, -1)), root
+                # one fused walk, one (..., T) totals vector — template
+                # j's entry comes from its own root table
+                totals = jnp.stack(
+                    [r.astype(acc_dt).sum(axis=(-2, -1)) for r in outs],
+                    axis=-1)
+                return totals, outs
 
         return run
 
@@ -830,9 +833,11 @@ class CountingEngine:
                 gathered = m_cols[col_ids, :].astype(acc_dt)
                 return acc + gathered * msk.astype(acc_dt)[:, None], None
 
-            acc0 = jnp.zeros(m_cols.shape, acc_dt)
-            acc, _ = jax.lax.scan(body, acc0, (ops["nbr"].T, ops["mask"].T))
-            return acc.astype(m_cols.dtype)
+            with _tracing.device_scope(_tracing.KERNEL_SPMM):
+                acc0 = jnp.zeros(m_cols.shape, acc_dt)
+                acc, _ = jax.lax.scan(body, acc0,
+                                      (ops["nbr"].T, ops["mask"].T))
+                return acc.astype(m_cols.dtype)
 
         def passive_op(ops, p_idx, m_p):
             # PFASCIA: one neighbor sweep per distinct passive set.
@@ -847,9 +852,10 @@ class CountingEngine:
                         * y_p[:, ip_l].astype(acc_dt))
                 return acc + prod, None
 
-            acc0 = jnp.zeros((m_a.shape[0], ia.shape[0]), acc_dt)
-            acc, _ = jax.lax.scan(body, acc0, (ia.T, ip.T))
-            return acc.astype(self.dtype)
+            with _tracing.device_scope(_tracing.KERNEL_EMA):
+                acc0 = jnp.zeros((m_a.shape[0], ia.shape[0]), acc_dt)
+                acc, _ = jax.lax.scan(body, acc0, (ia.T, ip.T))
+                return acc.astype(self.dtype)
 
         def combine_direct(ops, idx, m_a, m_p):
             # FASCIA: the neighbor sweep is *inside* the split loop —
@@ -861,9 +867,11 @@ class CountingEngine:
                 prod = m_a[:, idx_l[0]].astype(acc_dt) * y_l.astype(acc_dt)
                 return acc + prod, None
 
-            acc0 = jnp.zeros((m_a.shape[0], ia.shape[0]), acc_dt)
-            acc, _ = jax.lax.scan(body, acc0, (ia.T, ip.T))
-            return acc.astype(self.dtype)
+            # the sweep's own ops nest a kernel.spmm scope inside this one
+            with _tracing.device_scope(_tracing.KERNEL_EMA):
+                acc0 = jnp.zeros((m_a.shape[0], ia.shape[0]), acc_dt)
+                acc, _ = jax.lax.scan(body, acc0, (ia.T, ip.T))
+                return acc.astype(self.dtype)
 
         def run(ops: dict, colors: jax.Array):
             leaf = self._leaf_table_cn(colors).T  # (N, k)
@@ -873,11 +881,12 @@ class CountingEngine:
                 combine=combine, combine_direct=partial(combine_direct, ops),
                 on_step=self._peak_probe,
                 outputs=self.roots)
-            if not self.fused:
-                root = outs[0]
-                return root.astype(acc_dt).sum(), root
-            totals = jnp.stack([r.astype(acc_dt).sum() for r in outs])
-            return totals, outs
+            with _tracing.device_scope(_tracing.KERNEL_ROOT):
+                if not self.fused:
+                    root = outs[0]
+                    return root.astype(acc_dt).sum(), root
+                totals = jnp.stack([r.astype(acc_dt).sum() for r in outs])
+                return totals, outs
 
         return run
 

@@ -707,8 +707,7 @@ class PlanExecutor:
                 grp = group_of[idx]
                 if idx not in tables:
                     # leader step: one launch materializes EVERY member
-                    with _tracing.span("plan.node", idx=idx, size=node.size,
-                                       mode="fused_shared", group=len(grp)):
+                    with _tracing.device_scope(f"{_tracing.PLAN_NODE}{idx}"):
                         outs_g = combine_group(
                             grp, [tables[plan.nodes[m].active] for m in grp],
                             tables[node.passive])
@@ -719,14 +718,8 @@ class PlanExecutor:
                 m_a = tables[node.active]
                 direct = (not sched.passive_cache) \
                     or chunks.get(idx, 1) > 1 or idx in fset
-                mode = ("chunked" if chunks.get(idx, 1) > 1
-                        else "fused" if idx in fset
-                        else "direct" if direct else "cached")
-                # spans here run at jit-trace time (once per compiled
-                # shape): they expose per-node plan structure, not device
-                # time — that belongs to the engine's dispatch span
-                with _tracing.span("plan.node", idx=idx, size=node.size,
-                                   mode=mode):
+                # names this node's device ops (kernel scopes nest inside)
+                with _tracing.device_scope(f"{_tracing.PLAN_NODE}{idx}"):
                     if direct:
                         tables[idx] = combine_direct(idx, m_a,
                                                      tables[node.passive])

@@ -23,6 +23,7 @@ from repro.kernels import accum_dtype, resolve_interpret
 from repro.kernels import autotune as _autotune
 from repro.kernels.ema.pallas_ema import ema_pallas, ema_vmem_bytes
 from repro.obs import metrics as _metrics
+from repro.obs import tracing as _tracing
 
 __all__ = ["ema", "ema_xla", "ema_chunked", "pack_chunked_splits",
            "ChunkedSplits", "ema_flops", "pallas_supports_dtype",
@@ -96,7 +97,15 @@ def ema(m_a: jnp.ndarray, y_p: jnp.ndarray, ia: jnp.ndarray, ip: jnp.ndarray,
     supported and the tables fit VMEM at the chosen block sizes; a batched
     (B, C, N) input runs as ONE kernel launch (batch on the grid). Explicit
     ``s_block``/``n_block`` override the defaults; ``autotune=True`` sweeps
-    :data:`repro.kernels.autotune.EMA_BLOCK_CANDIDATES` once per shape."""
+    :data:`repro.kernels.autotune.EMA_BLOCK_CANDIDATES` once per shape.
+    Either path's device ops run under the ``kernel.ema`` scope."""
+    with _tracing.device_scope(_tracing.KERNEL_EMA):
+        return _ema(m_a, y_p, ia, ip, use_pallas, interpret, s_block,
+                    n_block, autotune)
+
+
+def _ema(m_a, y_p, ia, ip, use_pallas, interpret, s_block, n_block,
+         autotune) -> jnp.ndarray:
     dtype = jnp.promote_types(m_a.dtype, y_p.dtype)
     if use_pallas:
         interpret = resolve_interpret(interpret)
@@ -195,8 +204,14 @@ def ema_chunked(m_a: jnp.ndarray, m_p: jnp.ndarray, pack: ChunkedSplits,
     ellipsis) — one scan for the whole coloring batch, no per-element
     serialization. Peak extra memory is one passive chunk + one pair block
     instead of the whole ``C(k, t_p) x N`` table. Matches the unchunked path
-    to float reassociation (~1e-6 relative).
+    to float reassociation (~1e-6 relative). Its device ops run under the
+    ``kernel.ema`` scope; those of ``spmm_fn`` may name their own.
     """
+    with _tracing.device_scope(_tracing.KERNEL_EMA):
+        return _ema_chunked(m_a, m_p, pack, spmm_fn)
+
+
+def _ema_chunked(m_a, m_p, pack: ChunkedSplits, spmm_fn) -> jnp.ndarray:
     n = m_a.shape[-1]
     lead = m_a.shape[:-2]
     from repro.kernels.spmm.ops import spmm_row_chunks

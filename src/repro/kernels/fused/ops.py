@@ -34,6 +34,7 @@ from repro.kernels.fused.pallas_fused import (fused_spmm_ema_pallas,
                                               fused_spmm_ema_shared_pallas,
                                               group_batch_block_fits)
 from repro.obs import metrics as _metrics
+from repro.obs import tracing as _tracing
 
 __all__ = ["FusedPrep", "prepare_fused", "fused_spmm_ema",
            "fused_spmm_ema_shared", "fused_group_fits_vmem"]
@@ -119,8 +120,14 @@ def fused_spmm_ema(m_a: jnp.ndarray, m_p: jnp.ndarray,
     the kernel grid — one launch for the whole coloring batch). The vertex
     axis is padded to the tile multiple on the way in (padding vertices are
     isolated, so their neighbor sums and output columns are exact zeros) and
-    sliced on the way out.
+    sliced on the way out. Its device ops, fallback included, run under the
+    ``kernel.fused`` scope.
     """
+    with _tracing.device_scope(_tracing.KERNEL_FUSED):
+        return _fused_spmm_ema(m_a, m_p, ia, ip, prep)
+
+
+def _fused_spmm_ema(m_a, m_p, ia, ip, prep: FusedPrep) -> jnp.ndarray:
     st = prep.static
     dtype = jnp.promote_types(m_a.dtype, m_p.dtype)
     # every fallback decision is reason-counted (once per traced shape),
@@ -173,8 +180,14 @@ def fused_spmm_ema_shared(m_as, m_p: jnp.ndarray, ias, ips,
     """Per-consumer ``ema(m_a_i, m_p @ A, ia_i, ip_i)`` tuple for a group of
     consumers sharing one passive child. The Pallas path runs the SpMM leg
     once into shared VMEM scratch; tables have shape (..., C, N) with one
-    optional shared leading batch dimension.
+    optional shared leading batch dimension. Its device ops, fallback
+    included, run under the ``kernel.fused`` scope.
     """
+    with _tracing.device_scope(_tracing.KERNEL_FUSED):
+        return _fused_spmm_ema_shared(m_as, m_p, ias, ips, prep)
+
+
+def _fused_spmm_ema_shared(m_as, m_p, ias, ips, prep: FusedPrep) -> tuple:
     st = prep.static
     m_as, ias, ips = tuple(m_as), tuple(ias), tuple(ips)
     dtype = m_p.dtype

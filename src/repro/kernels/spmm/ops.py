@@ -27,6 +27,7 @@ from repro.kernels.ema import ops as ema_ops
 from repro.kernels.spmm.pallas_bsr import spmm_bsr_pallas
 from repro.kernels.spmm.pallas_gather import spmm_gather_pallas
 from repro.obs import metrics as _metrics
+from repro.obs import tracing as _tracing
 
 __all__ = ["prepare", "spmm", "spmm_row_chunks", "SpmmPrep", "METHODS"]
 
@@ -156,11 +157,17 @@ def spmm(m: jnp.ndarray, prep: SpmmPrep, *, c_block: int | None = None,
     XLA segment path on the prep's fallback edge lists instead (explicit
     fallback, never a downcast). ``c_block`` overrides the Pallas row-block
     heuristic; ``autotune=True`` sweeps candidates once per (shape, dtype).
+    Every backend's device ops run under the ``kernel.spmm`` scope.
     """
+    with _tracing.device_scope(_tracing.KERNEL_SPMM):
+        return _spmm(m, prep, c_block, autotune)
+
+
+def _spmm(m: jnp.ndarray, prep: SpmmPrep, c_block: int | None,
+          autotune: bool) -> jnp.ndarray:
     if m.ndim > 2:
         lead = m.shape[:-1]
-        out = spmm(m.reshape(-1, m.shape[-1]), prep, c_block=c_block,
-                   autotune=autotune)
+        out = _spmm(m.reshape(-1, m.shape[-1]), prep, c_block, autotune)
         return out.reshape(lead + (out.shape[-1],))
     a = prep.arrays
     if prep.method == "segment":

@@ -201,8 +201,8 @@ def parse_args(argv=None) -> argparse.Namespace:
                     help="write the metrics-registry snapshot (validated "
                          "JSON, schema v1) to FILE on exit")
     ap.add_argument("--profile-dir", default=None, metavar="DIR",
-                    help="arm a one-shot jax.profiler trace around the "
-                         "first device dispatch, written to DIR")
+                    help="write a jax.profiler trace of the whole run to "
+                         "DIR, with the program's spans on its clock")
     ap.add_argument("--http", type=int, default=None, metavar="PORT",
                     help="serving mode: run the async QoS service behind "
                          "an HTTP/JSON front end on PORT until SIGINT "
@@ -241,7 +241,13 @@ def main(argv=None):
     if args.trace:
         obs_tracing.configure(enabled=True, sync=True)
     if args.profile_dir:
-        obs_tracing.arm_profiler(args.profile_dir)
+        # one trace of the whole run, HTTP serving included, to shutdown
+        with obs_tracing.profile(args.profile_dir):
+            return _run(args)
+    return _run(args)
+
+
+def _run(args):
     if args.inject:
         from repro.resilience import faults as _faults
         plan = _faults.FaultPlan.parse(args.inject, seed=args.inject_seed)

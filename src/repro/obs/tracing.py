@@ -1,4 +1,4 @@
-"""Zero-dependency span tracer with a no-op fast path.
+"""Zero-dependency span tracer with a no-op fast path, and device scopes.
 
 One process-wide :class:`Tracer` (swap it with :func:`set_tracer`) produces
 nested, labeled :class:`Span`\\ s via the :func:`span` context manager::
@@ -11,21 +11,25 @@ Disabled (the default), :func:`span` returns one shared no-op context
 manager — no allocation beyond the kwargs dict, no clock read — so hot
 loops can be instrumented unconditionally. The tests bound this overhead.
 
-Two timing refinements for jit-dispatch instrumentation:
+Spans measure *host wall time of the code they wrap*. Device work is named
+instead, by :func:`device_scope` (a ``jax.named_scope``): every device op
+traced inside carries the scope path in its HLO ``op_name`` metadata, e.g.
+``jit(seeded)/plan.node3/kernel.spmm/while/body/scatter-add``, which a
+profiler trace reports per op. A scope changes metadata only, never the
+compiled program, so it costs nothing at run time. The scope names are the
+constants below, kept stable so that per-kernel device time can be compared
+across rewrites of a kernel.
+
+Two timing refinements:
 
 * ``sync=True`` makes :func:`sync_ready` call ``jax.block_until_ready``
   inside the enclosing span, so the span measures device time instead of
   async dispatch time (jax is imported lazily; the tracer itself has no
   jax dependency).
-* :func:`arm_profiler` arms a one-shot ``jax.profiler`` trace: the next
-  :func:`profiled_dispatch` block writes a device profile to the armed
-  directory, then disarms — one dispatch, not the whole run.
-
-Spans measure *host wall time of the code they wrap*. Code that runs under
-``jax.jit`` executes its Python body once per compiled shape (tracing), so
-spans inside jitted functions — e.g. the executor's per-node spans — record
-trace/compile-time structure; device time belongs to the span around the
-dispatch, with ``sync`` enabled.
+* ``profiler=True`` opens a ``jax.profiler.TraceAnnotation`` with each span
+  (its attributes included), putting the program's spans on the profiler's
+  clock beside the device ops. :func:`profile` turns it on for a whole run
+  while a ``jax.profiler`` trace is written.
 """
 
 from __future__ import annotations
@@ -36,14 +40,25 @@ import time
 
 __all__ = [
     "Span", "Tracer", "get_tracer", "set_tracer", "configure", "span",
-    "enabled", "sync_ready", "arm_profiler", "profiled_dispatch",
+    "enabled", "sync_ready", "device_scope", "profile",
+    "KERNEL_LEAF", "KERNEL_SPMM", "KERNEL_EMA", "KERNEL_FUSED", "KERNEL_ROOT",
+    "PLAN_NODE",
 ]
+
+# Device scope names (see :func:`device_scope`). A plan node's scope is
+# ``PLAN_NODE`` followed by its index in the plan, e.g. ``plan.node3``.
+KERNEL_LEAF = "kernel.leaf"      # one-hot leaf tables of a coloring
+KERNEL_SPMM = "kernel.spmm"      # neighbour sums, every SpMM backend
+KERNEL_EMA = "kernel.ema"        # split combination of child tables
+KERNEL_FUSED = "kernel.fused"    # SpMM and eMA in one Pallas launch
+KERNEL_ROOT = "kernel.root"      # sums over the root tables
+PLAN_NODE = "plan.node"
 
 
 class Span:
     """One timed, labeled region; nested spans become children."""
 
-    __slots__ = ("name", "attrs", "t0", "t1", "children", "_tracer")
+    __slots__ = ("name", "attrs", "t0", "t1", "children", "_tracer", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -52,6 +67,7 @@ class Span:
         self.t0 = 0.0
         self.t1 = 0.0
         self.children: list[Span] = []
+        self._ann = None
 
     @property
     def seconds(self) -> float:
@@ -101,12 +117,14 @@ _NULL = _NullSpan()
 
 
 class Tracer:
-    """Collects finished root spans; nesting follows a per-thread stack."""
+    """Collects finished root spans; nesting follows a per-thread stack.
+    With ``profiler`` set, each span is also a ``TraceAnnotation``."""
 
     def __init__(self, enabled: bool = True, sync: bool = False,
-                 max_roots: int = 10_000):
+                 max_roots: int = 10_000, profiler: bool = False):
         self.enabled = bool(enabled)
         self.sync = bool(sync)
+        self.profiler = bool(profiler)
         self.max_roots = int(max_roots)
         self.roots: list[Span] = []
         self._local = threading.local()
@@ -119,6 +137,14 @@ class Tracer:
         return st
 
     def _push(self, sp: Span) -> None:
+        if self.profiler:
+            import jax.profiler
+            # TraceMe encodes attributes as "name#k=v,...#": keep their
+            # values free of the two separators
+            sp._ann = jax.profiler.TraceAnnotation(sp.name, **{
+                k: str(v).replace(",", " ").replace("#", " ")
+                for k, v in sp.attrs.items()})
+            sp._ann.__enter__()
         self._stack().append(sp)
 
     def _pop(self, sp: Span) -> None:
@@ -129,6 +155,9 @@ class Tracer:
             st[-1].children.append(sp)
         elif len(self.roots) < self.max_roots:
             self.roots.append(sp)
+        if sp._ann is not None:
+            sp._ann.__exit__(None, None, None)
+            sp._ann = None
 
     # ------------------------------------------------------------------ api
     def span(self, name: str, **attrs):
@@ -139,9 +168,6 @@ class Tracer:
     def reset(self) -> None:
         self.roots = []
         self._local = threading.local()
-
-    def to_dicts(self) -> list[dict]:
-        return [r.to_dict() for r in self.roots]
 
     def breakdown(self) -> dict[str, dict]:
         """Aggregate ``{span name: {count, seconds}}`` over the whole tree."""
@@ -203,30 +229,31 @@ def sync_ready(x) -> None:
         jax.block_until_ready(x)
 
 
-# ------------------------------------------------------- one-shot profiler
-_profile_dir: list[str | None] = [None]
-
-
-def arm_profiler(trace_dir: str | None) -> None:
-    """Arm a one-shot ``jax.profiler`` trace: the next
-    :func:`profiled_dispatch` block writes a profile to ``trace_dir``."""
-    _profile_dir[0] = trace_dir
+def device_scope(name: str):
+    """Context manager naming the device ops traced inside it: a thin
+    ``jax.named_scope`` over one of the scope constants of this module."""
+    import jax
+    return jax.named_scope(name)
 
 
 @contextlib.contextmanager
-def profiled_dispatch():
-    """Wrap one dispatch; emits a jax profiler trace if one is armed.
+def profile(log_dir: str):
+    """Write a ``jax.profiler`` trace of the enclosed run to ``log_dir``,
+    with the process tracer enabled in profiler mode, so that its spans
+    (and their attributes) share the trace's clock with the device ops.
+    The tracer's switches are restored on exit. A profiler that fails to
+    start raises: a run that asked for a trace must not run untraced."""
+    import jax.profiler
 
-    A profiler that fails to start raises here: a run that asked for a
-    trace and silently ran untraced would look like a traced one."""
-    d = _profile_dir[0]
-    if d is None:
-        yield
-        return
-    _profile_dir[0] = None     # one-shot: disarm before running
-    import jax.profiler as prof
-    prof.start_trace(d)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0      # spans, not every Python call
+    opts.host_tracer_level = 2        # runtime events under the spans
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
+    t = _tracer
+    saved = (t.enabled, t.profiler)
+    t.enabled = t.profiler = True
     try:
-        yield
+        yield t
     finally:
-        prof.stop_trace()
+        t.enabled, t.profiler = saved
+        jax.profiler.stop_trace()

@@ -56,6 +56,7 @@ import time
 
 from repro.core import executor as pexec
 from repro.obs import metrics as _metrics
+from repro.obs import tracing as _tracing
 from repro.resilience import faults as _faults
 from repro.resilience.retry import RetryPolicy
 from repro.service.qos import (SHED_CLOSED, SHED_MEMORY, AdmissionQueue,
@@ -517,8 +518,11 @@ class AsyncCountingService(CountingService):
                     self._cv.notify_all()
                     return
                 pending = self._queue.drain()
-            for rid in pending:               # builds happen outside the
-                self._attach_async(rid)       # lock; submit stays live
+            if pending:
+                # builds happen outside the lock; submit stays live
+                with _tracing.span("service.attach", rids=" ".join(pending)):
+                    for rid in pending:
+                        self._attach_async(rid)
             picked = None
             with self._cv:
                 self._consume_and_retire()
@@ -535,7 +539,7 @@ class AsyncCountingService(CountingService):
                 # device work runs without the lock: admission, cancel,
                 # and waiters stay responsive during a dispatch
                 self._dispatch_ids(grp, ids)
-                with self._cv:
+                with _tracing.span("service.retire"), self._cv:
                     self._policy.charge(gv.tenants, len(ids))
                     self._consume_and_retire()
                     self._release_idle_engines()
@@ -551,7 +555,9 @@ class AsyncCountingService(CountingService):
                 continue
             with self._cv:
                 if self._running and not len(self._queue):
-                    self._cv.wait(self.idle_wait_s)
+                    # nothing to do until a submit or close notifies
+                    with _tracing.span("service.idle"):
+                        self._cv.wait(self.idle_wait_s)
 
     def _publish_inflight(self) -> None:
         n = sum(st.status in (RequestStatus.PENDING, RequestStatus.RUNNING)

@@ -59,6 +59,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 from repro.api import CountQuery
 from repro.obs import metrics as _metrics
+from repro.obs import tracing as _tracing
 from repro.resilience import faults as _faults
 from repro.service.async_loop import AsyncCountingService
 from repro.service.qos import QoS
@@ -195,25 +196,32 @@ class _Handler(BaseHTTPRequestHandler):
 
     def do_POST(self):
         req_id = f"h{next(_REQ_IDS):06d}"
-        if self.path != "/count":
-            self._send_json(404, {"error": f"no route {self.path!r}"})
-            return
-        try:
-            _faults.inject("http.handler", context=f"POST {self.path}")
-            n = int(self.headers.get("Content-Length", 0))
-            if n > _MAX_BODY:
-                self._send_json(413, {"error": "body too large"})
+        with _tracing.span("frontend.request", request_id=req_id) as sp:
+            if self.path != "/count":
+                self._send_json(404, {"error": f"no route {self.path!r}"})
                 return
-            body = json.loads(self.rfile.read(n) or b"{}")
-            self._post_count(body)
-        except (ValueError, KeyError, TypeError) as exc:
-            self._send_json(400, {"error": f"{type(exc).__name__}: {exc}",
-                                  "error_class": type(exc).__name__,
-                                  "request_id": req_id})
-        except Exception as exc:
-            self._send_error_500(exc, req_id)
+            try:
+                with _tracing.span("frontend.parse"):
+                    _faults.inject("http.handler",
+                                   context=f"POST {self.path}")
+                    n = int(self.headers.get("Content-Length", 0))
+                    if n > _MAX_BODY:
+                        self._send_json(413, {"error": "body too large"})
+                        return
+                    body = json.loads(self.rfile.read(n) or b"{}")
+                    rids = self._submit_count(body)
+                sp.set(rids=" ".join(rids))
+                self._answer_count(body, rids)
+            except (ValueError, KeyError, TypeError) as exc:
+                self._send_json(400, {"error": f"{type(exc).__name__}: {exc}",
+                                      "error_class": type(exc).__name__,
+                                      "request_id": req_id})
+            except Exception as exc:
+                self._send_error_500(exc, req_id)
 
-    def _post_count(self, body: dict) -> None:
+    def _submit_count(self, body: dict) -> list[str]:
+        """Validate a ``POST /count`` body and submit one service request
+        per template; returns their ids."""
         graph = body.get("graph", "g")
         tpls = body.get("templates", body.get("template"))
         if tpls is None:
@@ -233,37 +241,44 @@ class _Handler(BaseHTTPRequestHandler):
             plan=body.get("plan", "optimized"))
         query.validate()
         qos = _parse_qos(body.get("qos"))
-        rids = [self.svc.submit(CountRequest(
+        return [self.svc.submit(CountRequest(
             graph=graph, template=spec, engine=query.engine,
             plan=query.plan, rel_stderr=query.rel_stderr,
             max_iters=query.max_iters, min_iters=query.min_iters,
             seed=query.seed), qos=qos) for spec in query.templates]
+
+    def _answer_count(self, body: dict, rids: list[str]) -> None:
+        """Wait for the submitted requests (unless the body says not to)
+        and write their statuses and results."""
+        ids = " ".join(rids)
         if body.get("wait", True):
             # clamp: a client cannot park a handler thread past the
             # server's budget — unfinished work polls via /result/<rid>
             wait_s = min(float(body.get("timeout_s", _DEFAULT_TIMEOUT_S)),
                          getattr(self.server, "max_wait_s", _MAX_WAIT_S))
-            self.svc.wait(rids, wait_s)
-        out, n_done, n_shed = [], 0, 0
-        for rid in rids:
-            status = self.svc.status(rid)
-            ent = {"id": rid, "status": status.value}
-            if status is RequestStatus.DONE:
-                ent["result"] = self.svc.result(rid).to_dict()
-                n_done += 1
-            elif status is RequestStatus.SHED:
-                ent["reason"] = self.svc.shed_reason(rid)
-                n_shed += 1
-            elif status is RequestStatus.FAILED:
-                ent["error"] = self.svc._requests[rid].error
-                ent["error_class"] = self.svc._requests[rid].error_class
-            out.append(ent)
-        if n_shed == len(rids):
-            self._send_json(429, {"requests": out}, {"Retry-After": "1"})
-        elif n_done == len(rids):
-            self._send_json(200, {"requests": out})
-        else:
-            self._send_json(202, {"requests": out})
+            with _tracing.span("frontend.wait", rids=ids):
+                self.svc.wait(rids, wait_s)
+        with _tracing.span("frontend.respond", rids=ids):
+            out, n_done, n_shed = [], 0, 0
+            for rid in rids:
+                status = self.svc.status(rid)
+                ent = {"id": rid, "status": status.value}
+                if status is RequestStatus.DONE:
+                    ent["result"] = self.svc.result(rid).to_dict()
+                    n_done += 1
+                elif status is RequestStatus.SHED:
+                    ent["reason"] = self.svc.shed_reason(rid)
+                    n_shed += 1
+                elif status is RequestStatus.FAILED:
+                    ent["error"] = self.svc._requests[rid].error
+                    ent["error_class"] = self.svc._requests[rid].error_class
+                out.append(ent)
+            if n_shed == len(rids):
+                self._send_json(429, {"requests": out}, {"Retry-After": "1"})
+            elif n_done == len(rids):
+                self._send_json(200, {"requests": out})
+            else:
+                self._send_json(202, {"requests": out})
 
 
 def make_server(svc: AsyncCountingService, host: str = "127.0.0.1",

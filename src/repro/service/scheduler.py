@@ -531,36 +531,34 @@ class CountingService:
 
         per = None
         last_exc: BaseException | None = None
-        # an armed profiler traces this dispatch, retries included; one
-        # that cannot start raises out of the round instead of tracing
-        # nothing
-        with _tracing.profiled_dispatch():
-            for attempt in range(1, policy.max_attempts + 1):
-                t_disp = time.perf_counter()
-                try:
-                    with _tracing.span("service.dispatch",
-                                       group=grp.graph_name,
-                                       engine=grp.key[2], n=len(ids),
-                                       tenants=len(self._live_members(grp)),
-                                       attempt=attempt):
-                        per = run_with_timeout(
-                            attempt_fn, self.dispatch_watchdog_s(grp, len(ids)),
-                            name=grp.label)
+        rids = " ".join(r for r in grp.members
+                        if self._requests[r].status is RequestStatus.RUNNING)
+        for attempt in range(1, policy.max_attempts + 1):
+            t_disp = time.perf_counter()
+            try:
+                with _tracing.span("service.dispatch",
+                                   group=grp.graph_name,
+                                   engine=grp.key[2], n=len(ids),
+                                   tenants=len(self._live_members(grp)),
+                                   attempt=attempt, rids=rids):
+                    per = run_with_timeout(
+                        attempt_fn, self.dispatch_watchdog_s(grp, len(ids)),
+                        name=grp.label)
+                break
+            except Exception as exc:
+                last_exc = exc
+                reason = "timeout" if isinstance(exc, DispatchTimeout) \
+                    else "error"
+                if ladder.on_failure(reason=f"dispatch_{reason}"):
+                    try:
+                        self._rebuild_group_engine(grp, ladder)
+                    except Exception:
+                        pass    # keep the old engine; retry may still work
+                if attempt >= policy.max_attempts:
                     break
-                except Exception as exc:
-                    last_exc = exc
-                    reason = "timeout" if isinstance(exc, DispatchTimeout) \
-                        else "error"
-                    if ladder.on_failure(reason=f"dispatch_{reason}"):
-                        try:
-                            self._rebuild_group_engine(grp, ladder)
-                        except Exception:
-                            pass    # keep the old engine; retry may still work
-                    if attempt >= policy.max_attempts:
-                        break
-                    _metrics.counter("dispatch_retries_total",
-                                     reason=reason).inc()
-                    time.sleep(policy.delay(attempt, self._retry_rng))
+                _metrics.counter("dispatch_retries_total",
+                                 reason=reason).inc()
+                time.sleep(policy.delay(attempt, self._retry_rng))
         if per is None:
             breaker.on_failure()
             for m in self._live_members(grp):

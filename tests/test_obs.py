@@ -6,8 +6,13 @@ watermark and kernel-counter tests drive real engines/kernels to check the
 instrumentation fires on the paths it claims to cover.
 """
 
+import collections
+import glob
 import json
+import re
+import threading
 import time
+import urllib.request
 
 import numpy as np
 import pytest
@@ -101,6 +106,69 @@ class TestTracing:
         assert len(tracer.roots) == 5
         tracer.reset()
         assert tracer.roots == []
+
+    def test_profile_puts_spans_on_the_profiler_clock(self, tmp_path):
+        """``profile`` writes one trace whose host plane holds the
+        program's spans, attributes included, and restores the tracer."""
+        import jax.numpy as jnp
+        from jax.profiler import ProfileData
+
+        assert not tracing.enabled()
+        with tracing.profile(str(tmp_path)) as t:
+            assert t.enabled and t.profiler
+            with tracing.span("test.profiled", rids="r000001 r000002"):
+                jnp.ones(8).block_until_ready()
+        assert not tracing.enabled()
+        assert not tracing.get_tracer().profiler
+        (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                            recursive=True)
+        events = [(ev.name, dict(ev.stats))
+                  for plane in ProfileData.from_file(path).planes
+                  if plane.name.startswith("/host:")
+                  for line in plane.lines for ev in line.events]
+        assert ("test.profiled", {"rids": "r000001 r000002"}) in events
+
+
+# ---------------------------------------------------------- device scopes
+_SCOPED_OP = re.compile(r" = .*?\s(gather|scatter|reduce)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+# a transform may wrap a scope's name, as in ``vmap(plan.node3)``
+_SCOPE = re.compile(r"(?<![\w.])(plan\.node\d+|kernel\.[a-z]+)")
+
+
+class TestDeviceScopes:
+    @pytest.mark.parametrize("engine", ["pgbsc", "pfascia"])
+    def test_table_ops_sit_in_kernel_and_plan_node_scopes(self, engine):
+        """Every gather, scatter and reduce of a compiled u5 dispatch is
+        named by a kernel scope; those of a plan node's kernels sit inside
+        that node's scope (the ``op_name`` a device trace reports)."""
+        import jax
+        import jax.numpy as jnp
+
+        eng = build_engine(_graph(40), "u5", engine)
+        eng.warm(2)
+        hlo = eng._seeded().lower(
+            eng._operands(), jax.ShapeDtypeStruct((), jnp.int32),
+            jax.ShapeDtypeStruct((2,), jnp.int32)).compile().as_text()
+        per_kernel = collections.Counter()
+        for line in hlo.splitlines():
+            if not _SCOPED_OP.search(line):
+                continue
+            m = _OP_NAME.search(line)
+            assert m, line
+            scopes = _SCOPE.findall(m.group(1))
+            kernels = [i for i, sc in enumerate(scopes)
+                       if sc.startswith("kernel.")]
+            assert kernels, m.group(1)
+            kernel = scopes[kernels[-1]]
+            per_kernel[kernel] += 1
+            if kernel in (tracing.KERNEL_SPMM, tracing.KERNEL_EMA,
+                          tracing.KERNEL_FUSED):
+                assert any(sc.startswith(tracing.PLAN_NODE)
+                           for sc in scopes[:kernels[-1]]), m.group(1)
+        assert per_kernel[tracing.KERNEL_SPMM] >= 1
+        assert per_kernel[tracing.KERNEL_EMA] >= 1
+        assert per_kernel[tracing.KERNEL_ROOT] >= 1
 
 
 # --------------------------------------------------------------- metrics
@@ -326,3 +394,56 @@ class TestServiceObservability:
         assert agg["service.dispatch"]["count"] >= 1
         assert agg["engine_cache.build"]["count"] == 1
         assert agg["runner.checkpoint"]["count"] >= 1
+
+    def test_http_request_spans_share_its_id(self, registry, tracer,
+                                            tmp_path):
+        """One ``POST /count`` through the front end and the async
+        dispatcher: the front end's phases nest under its request span,
+        every span of the request carries its service id, and the
+        dispatcher's retire and idle time have spans of their own."""
+        from repro.service import AsyncCountingService
+        from repro.service.frontend import make_server
+
+        svc = AsyncCountingService(ledger_root=str(tmp_path / "http"),
+                                   round_size=4, default_max_iters=8,
+                                   idle_wait_s=0.01)
+        svc.add_graph("g", _graph())
+        svc.start()
+        httpd = make_server(svc, "127.0.0.1", 0)
+        threading.Thread(target=httpd.serve_forever, daemon=True).start()
+        try:
+            req = urllib.request.Request(
+                f"http://127.0.0.1:{httpd.server_address[1]}/count",
+                data=json.dumps({"graph": "g", "templates": ["u3"],
+                                 "max_iters": 4}).encode(),
+                headers={"Content-Type": "application/json"})
+            with urllib.request.urlopen(req, timeout=120) as resp:
+                (ent,) = json.load(resp)["requests"]
+            # the handler closes its request span after the reply is sent
+            deadline = time.monotonic() + 30
+            while time.monotonic() < deadline and not any(
+                    r.name == "frontend.request" for r in tracer.roots):
+                time.sleep(0.01)
+        finally:
+            httpd.shutdown()
+            svc.close()
+        rid = ent["id"]
+        by_name = collections.defaultdict(list)
+
+        def walk(sp):
+            by_name[sp.name].append(sp)
+            for c in sp.children:
+                walk(c)
+
+        for r in list(tracer.roots):
+            walk(r)
+        (request,) = by_name["frontend.request"]
+        assert [c.name for c in request.children] == [
+            "frontend.parse", "frontend.wait", "frontend.respond"]
+        assert request.attrs["request_id"].startswith("h")
+        for name in ("frontend.request", "frontend.wait",
+                     "frontend.respond", "service.attach",
+                     "service.dispatch"):
+            assert any(rid in sp.attrs.get("rids", "").split()
+                       for sp in by_name[name]), name
+        assert by_name["service.retire"] and by_name["service.idle"]
