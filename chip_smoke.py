@@ -50,12 +50,13 @@ RMAT_SERVED_SCALE = 20
 # Per-engine table budget: the executor's model puts u7's tables at
 # 2.6 GB for a dispatch of SERVED_ROUND colorings, and a v5e chip peaked
 # at 3.0 GB serving them. Compiled ahead of time for the chip, the u7
-# program reports 17.3 GB of temporaries at 16 colorings (8.4 GB at 8),
+# program reports 17.3 GB of temporaries at 16 colorings (8.2 GB at 8),
 # more than the chip's 16 GB, so the round stays at 8.
 SERVED_BUDGET_MB = 3072
 SERVED_ROUND = 8              # colorings per dispatch
-# The served phase runs its segment SpMM at about 0.5 s per table row on a
-# v5e chip, so its cost is counted in rows (``spmm_cols_per_coloring``):
+# The served phase runs its segment SpMM at RMAT-20 at about 0.16 s per
+# table row on a v5e chip in 32-row steps (0.27 s a row for an 8-row table
+# in one step), so its cost is counted in rows (``spmm_cols_per_coloring``):
 # u5 10 and u7 42 per coloring, and 8 for this 4-vertex tree rooted at a
 # leaf of its star.
 TREE_EDGES = "0-1,1-2,1-3@0"

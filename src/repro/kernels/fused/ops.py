@@ -33,6 +33,7 @@ from repro.kernels.ema.ops import ema_xla, pallas_supports_dtype
 from repro.kernels.fused.pallas_fused import (fused_spmm_ema_pallas,
                                               fused_spmm_ema_shared_pallas,
                                               group_batch_block_fits)
+from repro.kernels.spmm.ops import _spmm_segment, edges_sorted_by_dst
 from repro.obs import metrics as _metrics
 from repro.obs import tracing as _tracing
 
@@ -74,7 +75,7 @@ def prepare_fused(g: Graph, *, tile: int = 128,
     autotune cache key, same as ``spmm.ops.prepare``."""
     gp = g.padded(tile)
     bs = gp.bsr(tile=tile)
-    src, dst = g.edges_by_dst
+    src, dst = edges_sorted_by_dst(g)
     return FusedPrep(
         g.n,
         {"blocks": jnp.asarray(bs.blocks, jnp.dtype(dtype)),
@@ -100,7 +101,6 @@ def fused_group_fits_vmem(c_as, c_p: int, ss, ls, *, tile: int = 128,
 def _fallback(m_a, m_p, ia, ip, prep: FusedPrep) -> jnp.ndarray:
     """Unfused XLA pair — the explicit escape hatch for unsupported dtypes
     or VMEM-oversized tables (matches the kernel to float reassociation)."""
-    from repro.kernels.spmm.ops import _spmm_segment
     _metrics.counter("kernel_launches_total", kernel="fused",
                      path="xla").inc()
     lead = m_p.shape[:-2]
@@ -163,7 +163,6 @@ def _fused_spmm_ema(m_a, m_p, ia, ip, prep: FusedPrep) -> jnp.ndarray:
 def _fallback_shared(m_as, m_p, ias, ips, prep: FusedPrep) -> tuple:
     """Shared fallback: the SpMM still runs ONCE (the sharing survives the
     escape hatch), then one XLA eMA per consumer."""
-    from repro.kernels.spmm.ops import _spmm_segment
     _metrics.counter("kernel_launches_total", kernel="fused_shared",
                      path="xla").inc()
     lead = m_p.shape[:-2]

@@ -5,7 +5,10 @@ backend needs; ``spmm(m, prep)`` applies Y = M @ A. All backends agree with
 ``ref.spmm_dense`` / ``ref.spmm_segment_ref`` (tests sweep shapes and dtypes).
 
 Backends:
-  segment       chunked gather + segment_sum over edges (XLA; default on CPU)
+  segment       gather + segment_sum over edges in dst order, the scatter told
+                its indices are sorted (XLA; the served default): a whole
+                table per step, rows chunked only past a byte budget
+                (``_spmm_segment``)
   ell           padded neighbor-list gather (XLA; good for low max-degree)
   dense         dense matmul (tiny graphs / oracle)
   pallas_gather on-the-fly densified edge chunks on the MXU (TPU target)
@@ -19,6 +22,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.graph.structure import Graph
 from repro.kernels import resolve_interpret
@@ -33,9 +37,13 @@ __all__ = ["prepare", "spmm", "spmm_row_chunks", "SpmmPrep", "METHODS"]
 
 METHODS = ("segment", "ell", "dense", "pallas_gather", "pallas_bsr")
 
-# Target elements for the (rows x edges) gather intermediate of the segment
-# backend; keeps peak memory bounded while amortizing scan overhead.
-_SEGMENT_TARGET_ELEMS = 1 << 24
+# Bytes of the (rows, E) gathered operand, in the accumulator dtype, that one
+# step of the segment SpMM may hold. A u7 table at Graph500 scale 18 (70 rows
+# x 7.6M slots, 2.1 GB) goes through in one step; RMAT-20's 280 rows x 31.4M
+# slots take 32-row steps. It bounds the program where XLA writes the
+# operand out: the CPU backend, and a TPU v5e at RMAT-20. At scale 18 the
+# v5e fuses the sorted gather into the scatter and writes nothing.
+_SEGMENT_GATHER_BUDGET_BYTES = 4 << 30
 
 
 @jax.tree_util.register_pytree_node_class
@@ -73,7 +81,7 @@ def prepare(g: Graph, method: str = "segment", *, tile: int = 128,
         raise ValueError(f"unknown spmm method {method!r}")
     interpret = resolve_interpret(interpret)
     if method == "segment":
-        src, dst = g.edges_by_dst
+        src, dst = edges_sorted_by_dst(g)
         return SpmmPrep(method, g.n,
                         {"src": jnp.asarray(src), "dst": jnp.asarray(dst)}, {})
     if method == "ell":
@@ -85,7 +93,7 @@ def prepare(g: Graph, method: str = "segment", *, tile: int = 128,
     # Pallas backends also carry the raw edge lists so a dtype the kernel
     # does not support can fall back to the XLA segment path explicitly
     # (never a silent downcast).
-    fb_src, fb_dst = g.edges_by_dst
+    fb_src, fb_dst = edges_sorted_by_dst(g)
     fb = {"fb_src": jnp.asarray(fb_src), "fb_dst": jnp.asarray(fb_dst)}
     adj_dtype = jnp.dtype(dtype)
     if method == "pallas_gather":
@@ -113,25 +121,62 @@ def prepare(g: Graph, method: str = "segment", *, tile: int = 128,
     )
 
 
+def edges_sorted_by_dst(g: Graph) -> tuple[np.ndarray, np.ndarray]:
+    """``g.edges_by_dst``, checked on the host to be in non-decreasing dst
+    order: ``_spmm_segment`` tells XLA its scatter indices are sorted, and
+    unsorted indices would give wrong sums without an error."""
+    src, dst = g.edges_by_dst
+    if not np.all(dst[1:] >= dst[:-1]):
+        raise ValueError("segment SpMM edge lists must be sorted by dst "
+                         "(as Graph.edges_by_dst builds them)")
+    return src, dst
+
+
+def _segment_row_chunk(c: int, e: int, itemsize: int) -> int:
+    """Rows per step: all ``c`` when the gathered (c, e) operand fits
+    ``_SEGMENT_GATHER_BUDGET_BYTES``, else the largest multiple of 8 that
+    fits (at least one row)."""
+    fit = _SEGMENT_GATHER_BUDGET_BYTES // (max(e, 1) * itemsize)
+    if c <= fit:
+        return c
+    return fit // 8 * 8 if fit >= 8 else max(fit, 1)
+
+
 def _spmm_segment(m: jnp.ndarray, src, dst, n: int) -> jnp.ndarray:
+    """Y = M @ A for a (C, N) table as a gather of ``m[:, src]`` and a
+    ``segment_sum`` by ``dst``.
+
+    Precondition: ``dst`` is non-decreasing, as ``Graph.edges_by_dst`` builds
+    it (``edges_sorted_by_dst`` checks it once per prep). The scatter is
+    told its indices are sorted, so XLA neither sorts them nor permutes the
+    updates. Rows go through in one step unless the gathered (C, E) operand
+    would exceed ``_SEGMENT_GATHER_BUDGET_BYTES``; past it, a scan over row
+    chunks (a ragged last chunk zero-padded). So each edge index is gathered
+    and scattered once per table, or per chunk past the budget; on a TPU v5e
+    at Graph500 scale 18 the gather fuses into the scatter and the operand
+    is never written. Each vertex sums its edges in edge order; sub-f32
+    storage accumulates in f32 (the kernels' storage/accum contract) and
+    casts back at the end.
+    """
     store = m.dtype
     acc_dt = ema_ops.accum_dtype(store)
     c = m.shape[0]
-    e = max(int(src.shape[0]), 1)
-    row_chunk = max(1, min(c, _SEGMENT_TARGET_ELEMS // e))
+
+    def rows(chunk):
+        contrib = chunk[:, src].astype(acc_dt)                      # (rc, E)
+        out = jax.ops.segment_sum(contrib.T, dst, num_segments=n,
+                                  indices_are_sorted=True)          # (N, rc)
+        return out.T.astype(store)
+
+    row_chunk = _segment_row_chunk(c, int(src.shape[0]),
+                                   jnp.dtype(acc_dt).itemsize)
+    if row_chunk >= c:
+        return rows(m)
     n_chunks = -(-c // row_chunk)
     c_pad = n_chunks * row_chunk
     m_p = jnp.pad(m, ((0, c_pad - c), (0, 0))) if c_pad != c else m
     m_p = m_p.reshape(n_chunks, row_chunk, m.shape[1])
-
-    def body(_, chunk):
-        # sub-f32 storage accumulates its edge sums in f32 (same
-        # storage/accum contract as the kernels) and casts back at the end
-        contrib = chunk[:, src].astype(acc_dt)                    # (rc, E)
-        out = jax.ops.segment_sum(contrib.T, dst, num_segments=n)  # (N, rc)
-        return None, out.T.astype(store)
-
-    _, out = jax.lax.scan(body, None, m_p)
+    _, out = jax.lax.scan(lambda _, chunk: (None, rows(chunk)), None, m_p)
     return out.reshape(c_pad, m.shape[1])[:c]
 
 

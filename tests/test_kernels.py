@@ -6,10 +6,12 @@ import numpy as np
 import pytest
 
 from repro.graph import erdos_renyi, grid_2d, rmat, star
-from repro.graph.reorder import apply_order, rcm_order
+from repro.graph.reorder import apply_order, degree_order, rcm_order
+from repro.graph.structure import Graph
 from repro.kernels.ema.ops import ema, ema_xla
 from repro.kernels.ema.pallas_ema import ema_pallas
 from repro.kernels.ema.ref import ema_ref
+from repro.kernels.fused.ops import prepare_fused
 from repro.kernels.spmm import ops as spmm_ops
 from repro.kernels.spmm.pallas_bsr import spmm_bsr_pallas
 from repro.kernels.spmm.pallas_gather import spmm_gather_pallas
@@ -50,6 +52,102 @@ class TestSpmmXlaBackends:
         got = spmm_segment_ref(m, jnp.asarray(src), jnp.asarray(dst), g.n)
         want = spmm_dense(m, jnp.asarray(g.to_dense()))
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0)
+
+
+def _isolated_graph():
+    """A path and a triangle among 40 vertices: most vertices have no edge,
+    so many dst segments are empty (leading, interior and trailing)."""
+    return Graph.from_edges(40, np.array([[3, 4], [4, 5], [5, 6],
+                                          [20, 21], [21, 22], [22, 20]]))
+
+
+SEGMENT_GRAPHS = {
+    "er_small": GRAPHS["er_small"],
+    "star_hub": GRAPHS["star_skew"],     # one vertex holds half the slots
+    "isolated": _isolated_graph,
+}
+
+
+class TestSpmmSegment:
+    """``_spmm_segment``: sorted scatter, rows in one step or in budgeted
+    chunks, exact against the unchunked reference and the dense oracle."""
+
+    @pytest.mark.parametrize("gname", sorted(SEGMENT_GRAPHS))
+    @pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    # (rows, rows the patched budget admits): below, at and across it, a
+    # ragged last chunk, and a budget under 8 rows
+    @pytest.mark.parametrize("c,fit", [(3, 8), (8, 8), (20, 8), (11, 11),
+                                       (12, 11), (5, 3), (40, None)])
+    def test_matches_ref_and_dense(self, monkeypatch, gname, dtype, c, fit):
+        g = SEGMENT_GRAPHS[gname]()
+        src, dst = g.edges_by_dst
+        if fit is not None:     # acc dtype is f32 for both storages
+            monkeypatch.setattr(spmm_ops, "_SEGMENT_GATHER_BUDGET_BYTES",
+                                fit * src.size * 4)
+        rng = np.random.default_rng(c)
+        m = _rand_table(rng, c, g.n).astype(dtype)
+        got = spmm_ops._spmm_segment(m, jnp.asarray(src), jnp.asarray(dst),
+                                     g.n)
+        assert got.dtype == m.dtype and got.shape == m.shape
+        m32 = m.astype(jnp.float32)
+        ref = spmm_segment_ref(m32, jnp.asarray(src), jnp.asarray(dst), g.n)
+        dense = spmm_dense(m32, jnp.asarray(g.to_dense()))
+        # integer tables: f32 sums are exact, one cast back to the storage
+        for want in (ref, dense):
+            np.testing.assert_allclose(
+                np.asarray(got, np.float32),
+                np.asarray(want.astype(dtype), np.float32), rtol=0)
+
+    @pytest.mark.parametrize("c,e,itemsize,budget,want", [
+        (70, 7_600_000, 4, None, 70),         # g500-s18 u7 node 3: one step
+        (14, 7_600_000, 4, None, 14),         # its node 1
+        (280, 31_403_422, 4, None, 32),       # RMAT-20 at 8 colorings
+        (280, 31_403_422, 2, None, 64),
+        (20, 100, 4, 8 * 100 * 4, 8),
+        (20, 100, 4, 13 * 100 * 4, 8),        # largest multiple of 8
+        (20, 100, 4, 5 * 100 * 4, 5),         # under 8 rows fit
+        (20, 100, 4, 10, 1),                  # not one row fits
+        (3, 0, 4, 1, 1),                      # no edges
+    ])
+    def test_row_chunk(self, monkeypatch, c, e, itemsize, budget, want):
+        if budget is not None:
+            monkeypatch.setattr(spmm_ops, "_SEGMENT_GATHER_BUDGET_BYTES",
+                                budget)
+        assert spmm_ops._segment_row_chunk(c, e, itemsize) == want
+
+    def test_no_edges(self):
+        g = Graph.from_edges(9, np.zeros((0, 2), np.int64))
+        m = _rand_table(np.random.default_rng(0), 4, g.n)
+        got = spmm_ops.spmm(m, spmm_ops.prepare(g, "segment"))
+        np.testing.assert_array_equal(np.asarray(got), 0)
+
+    @pytest.mark.parametrize("method", ["segment", "pallas_gather",
+                                        "pallas_bsr", "fused"])
+    def test_prepare_refuses_unsorted_dst(self, monkeypatch, method):
+        g = GRAPHS["er_small"]()
+        src, dst = g.edges_by_dst
+        perm = np.random.default_rng(0).permutation(src.size)
+        monkeypatch.setattr(Graph, "edges_by_dst",
+                            property(lambda self: (src[perm], dst[perm])))
+        with pytest.raises(ValueError, match="sorted by dst"):
+            if method == "fused":
+                prepare_fused(g)
+            else:
+                spmm_ops.prepare(g, method)
+
+    @pytest.mark.parametrize("gname", sorted(GRAPHS) + ["rmat_rcm",
+                                                        "rmat_degree"])
+    def test_edges_by_dst_sorted(self, gname):
+        orders = {"rmat_rcm": rcm_order, "rmat_degree": degree_order}
+        if gname in orders:
+            g = GRAPHS["rmat"]()
+            g = apply_order(g, orders[gname](g))
+        else:
+            g = GRAPHS[gname]()
+        src, dst = g.edges_by_dst
+        assert src.size == dst.size == g.m
+        assert np.all(np.diff(dst) >= 0)
 
 
 class TestSpmmPallas:

@@ -10,7 +10,9 @@ or how fast (the kernel tests and ``chip_smoke.py`` cover that).
 Shapes are the ``u7`` template's plan nodes at a coloring batch of 8:
 
 * the segment SpMM and the eMA at Graph500 RMAT scale 20 (``N = 2**20``
-  vertices, 31,403,422 edge slots);
+  vertices, 31,403,422 edge slots), and the segment SpMM at the benchmark's
+  Graph500 scale-18 u7 node 3 (two colorings of 35 rows, 7,600,000 edge
+  slots), where the whole table goes through one fused gather-scatter;
 * the gather and BSR SpMMs and the fused kernels at RMAT scale 14. These
   kernels prefetch one source and one destination tile id per edge chunk
   or adjacency block into the core's 1 MiB SMEM, which caps the stream at
@@ -20,6 +22,7 @@ Shapes are the ``u7`` template's plan nodes at a coloring batch of 8:
 """
 
 import os
+import re
 from math import comb
 
 import jax
@@ -40,6 +43,8 @@ BATCH = 8
 K = 7
 N20 = 1 << 20                     # RMAT scale 20
 EDGES20 = 31_403_422              # its directed edge slots (seed 0)
+N18 = 1 << 18                     # Graph500 scale 18 (the benchmark's)
+EDGES18 = 7_600_000               # its edge slots
 N14 = 1 << 14                     # RMAT scale 14
 CHUNKS14 = 12_176                 # its 512-slot edge chunks
 BLOCKS14 = 12_037                 # its BSR blocks (no empty tile)
@@ -102,11 +107,28 @@ def test_gather_spmm_rmat14(one_chip):
     assert _is_kernel(compiled)
 
 
+def _hlo_ops(compiled, op: str) -> int:
+    return len(re.findall(rf"\s{op}\(", compiled.as_text()))
+
+
 def test_segment_spmm_rmat20(one_chip):
+    # sorted indices: XLA sorts nothing before the scatter
     rows = BATCH * comb(K, 3)
-    _compile(lambda m, s, d: _spmm_segment(m, s, d, N20), one_chip,
-             ((rows, N20), jnp.float32), ((EDGES20,), jnp.int32),
-             ((EDGES20,), jnp.int32))
+    compiled = _compile(lambda m, s, d: _spmm_segment(m, s, d, N20),
+                        one_chip, ((rows, N20), jnp.float32),
+                        ((EDGES20,), jnp.int32), ((EDGES20,), jnp.int32))
+    assert _hlo_ops(compiled, "sort") == 0
+
+
+def test_segment_spmm_g500_s18_node3(one_chip):
+    # the whole table in one step: no loop, no sort, one scatter
+    rows = 2 * comb(K, 3)
+    compiled = _compile(lambda m, s, d: _spmm_segment(m, s, d, N18),
+                        one_chip, ((rows, N18), jnp.float32),
+                        ((EDGES18,), jnp.int32), ((EDGES18,), jnp.int32))
+    assert _hlo_ops(compiled, "sort") == 0
+    assert _hlo_ops(compiled, "while") == 0
+    assert _hlo_ops(compiled, "scatter") == 1
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
