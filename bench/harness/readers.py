@@ -63,3 +63,15 @@ def idle_share(run) -> float | None:
     if tr is None or tr["window_s"] <= 0:
         return None
     return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
+
+
+def kernel_s_per_coloring(run, kernel: str) -> float | None:
+    """Self seconds of the device ops under a ``kernel`` scope (in any plan
+    node, summed over the chips) in the traced window, over the colorings
+    answered in it."""
+    n = colorings(in_trace(run))
+    if not n:
+        return None
+    s = sum(t for key, t in run.trace["device_scopes"].items()
+            if kernel in key.split("/"))
+    return s / n if s > 0 else None

@@ -30,25 +30,61 @@ import jax.numpy as jnp
 import numpy as np
 
 EDGE_BLOCK = 1 << 20
+# Bytes of one gathered float32 operand: the ``(block, columns)`` edge block
+# of a neighbour-sum step, and the ``(vertices, splits)`` factor of a split
+# product. Every u7 table (35 columns, 140 splits at most) stays in one piece
+# at the benchmark's scale 18; a k = 12 table of 792 columns takes 2^16 edge
+# slots a step, and its 5,544 splits 2^13 vertices a step.
+_BLOCK_BUDGET_BYTES = 256 << 20
 
 
 def automorphisms(edges) -> int:
-    """Automorphisms of the unrooted tree, by trying every permutation."""
-    k = len(edges) + 1
-    es = {frozenset(e) for e in edges}
-    return sum(all(frozenset((p[u], p[v])) in es for u, v in edges)
-               for p in itertools.permutations(range(k)))
+    """Automorphisms of the unrooted tree, from canonical forms: the tree
+    is rooted at its center, or cut at its central edge into two rooted
+    halves, which a map may swap where they are alike."""
+    centers = _centers(edges)
+    if len(centers) == 1:
+        c = centers[0]
+        return _pieces(_children(edges, c), c)[c][1]
+    a, b = centers
+    halves = [e for e in edges if {e[0], e[1]} != {a, b}]
+    form_a, aut_a = _pieces(_children(halves, a), a)[a]
+    form_b, aut_b = _pieces(_children(halves, b), b)[b]
+    return aut_a * aut_b * (2 if form_a == form_b else 1)
+
+
+def _centers(edges) -> list[int]:
+    """The one or two vertices left after stripping leaves layer by layer."""
+    nbr = _neighbours(edges) or {0: []}       # k = 1: no edges
+    deg = {x: len(ys) for x, ys in nbr.items()}
+    layer = [x for x, d in deg.items() if d <= 1]
+    left = len(nbr)
+    while left > 2:
+        left -= len(layer)
+        nxt = []
+        for x in layer:
+            for y in nbr[x]:
+                deg[y] -= 1
+                if deg[y] == 1:
+                    nxt.append(y)
+        layer = nxt
+    return sorted(layer)
 
 
 def colorful_probability(k: int) -> float:
     return math.factorial(k) / k ** k
 
 
-def _children(edges, root: int) -> dict[int, list[int]]:
+def _neighbours(edges) -> dict[int, list[int]]:
     nbr: dict[int, list[int]] = {}
     for u, v in edges:
         nbr.setdefault(u, []).append(v)
         nbr.setdefault(v, []).append(u)
+    return nbr
+
+
+def _children(edges, root: int) -> dict[int, list[int]]:
+    nbr = _neighbours(edges)
     kids, seen, stack = {}, {root}, [root]
     while stack:
         x = stack.pop()
@@ -58,16 +94,24 @@ def _children(edges, root: int) -> dict[int, list[int]]:
     return kids
 
 
-def _shapes(kids: dict[int, list[int]], root: int) -> dict[int, str]:
-    """A canonical name of the rooted piece below each template vertex:
-    two vertices get one name exactly when their pieces are isomorphic."""
-    out: dict[int, str] = {}
+def _pieces(kids: dict[int, list[int]],
+            root: int) -> dict[int, tuple[str, int]]:
+    """``(canonical form, automorphisms)`` of the rooted piece below each
+    template vertex: two vertices get one form exactly when their pieces
+    are isomorphic, and a piece's automorphisms are its children's times
+    ``m!`` for each class of ``m`` children whose pieces are alike."""
+    out: dict[int, tuple[str, int]] = {}
 
-    def name(x: int) -> str:
-        out[x] = "(" + "".join(sorted(name(c) for c in kids[x])) + ")"
+    def walk(x: int) -> tuple[str, int]:
+        below = sorted(walk(c) for c in kids[x])
+        aut = 1
+        for _, alike in itertools.groupby(below, key=lambda fa: fa[0]):
+            alike = [a for _, a in alike]
+            aut *= math.factorial(len(alike)) * math.prod(alike)
+        out[x] = "(" + "".join(f for f, _ in below) + ")", aut
         return out[x]
 
-    name(root)
+    walk(root)
     return out
 
 
@@ -119,8 +163,18 @@ class Reference:
     def _neighbour_sum(self, t: jnp.ndarray, src: jnp.ndarray,
                        dst: jnp.ndarray) -> jnp.ndarray:
         """``y[v] = sum of t[u] over edge slots (u, v)``; t is (n, c), the
-        slots ``(src, dst)`` in blocks."""
+        slots ``(src, dst)`` in blocks, cut into smaller power-of-two blocks
+        where a block of ``c`` gathered columns would pass the budget."""
         n = self.n
+        fit = _BLOCK_BUDGET_BYTES // (4 * t.shape[1])
+        if src.shape[1] > fit:
+            step = 1 << max(0, fit.bit_length() - 1)
+            pad = -src.size % step
+            # padded slots add into the spare segment ``n``; every block is
+            # a slice of the dst-sorted slots, so it stays sorted
+            src = jnp.pad(src.reshape(-1), (0, pad)).reshape(-1, step)
+            dst = jnp.pad(dst.reshape(-1), (0, pad),
+                          constant_values=n).reshape(-1, step)
 
         def body(acc, blk):
             s, d = blk
@@ -131,10 +185,26 @@ class Reference:
         acc, _ = jax.lax.scan(body, acc0, (src, dst))
         return acc[:n]
 
+    def _split_product(self, t: jnp.ndarray, y: jnp.ndarray, ia: np.ndarray,
+                       ib: np.ndarray) -> jnp.ndarray:
+        """``out[v, j] = sum over i of t[v, ia[j, i]] * y[v, ib[j, i]]``, in
+        power-of-two chunks of vertices where one gathered factor of all
+        ``n`` would pass the budget (each vertex's sum is its own)."""
+        fit = _BLOCK_BUDGET_BYTES // (4 * ia.size)
+        if self.n <= fit:
+            return jnp.sum(t[:, ia] * y[:, ib], axis=-1)
+        rows = 1 << max(0, fit.bit_length() - 1)
+        pad = ((0, -self.n % rows), (0, 0))
+        out = jax.lax.map(
+            lambda ty: jnp.sum(ty[0][:, ia] * ty[1][:, ib], axis=-1),
+            (jnp.pad(t, pad).reshape(-1, rows, t.shape[1]),
+             jnp.pad(y, pad).reshape(-1, rows, y.shape[1])))
+        return out.reshape(-1, ia.shape[0])[:self.n]
+
     def _program(self, edges: tuple, root: int):
         k = len(edges) + 1
         kids = _children(edges, root)
-        shape = _shapes(kids, root)
+        shape = {x: form for x, (form, _) in _pieces(kids, root).items()}
 
         # the edge arrays are arguments, not constants: the compiled program
         # then does not depend on the graph, and the compile cache keeps it
@@ -156,7 +226,7 @@ class Reference:
                         tc, sc = table(c)
                         y = self._neighbour_sum(tc, src, dst)
                         ia, ib = _splits(k, size, sc)
-                        t = jnp.sum(t[:, ia] * y[:, ib], axis=-1)
+                        t = self._split_product(t, y, ia, ib)
                         size += sc
                     memo[shape[x]] = (t, size)
                     return t, size
@@ -186,5 +256,9 @@ class Reference:
     @staticmethod
     def scale(edges) -> float:
         """One sample of the estimator is ``count * scale``."""
-        return 1.0 / (automorphisms(edges)
-                      * colorful_probability(len(edges) + 1))
+        return _scale(tuple(tuple(int(x) for x in e) for e in edges))
+
+
+@lru_cache(maxsize=None)
+def _scale(edges: tuple) -> float:
+    return 1.0 / (automorphisms(edges) * colorful_probability(len(edges) + 1))

@@ -12,6 +12,10 @@ reduction reads the ``.xplane.pb`` with nothing but JAX:
   (time not covered by ops nested inside them);
 * the longest idle gaps, each labelled by the innermost host span open at
   the gap's midpoint.
+
+A session's reduction adds, from the same events, the device time by the
+program's scopes and the idle time by host span
+(:mod:`bench.harness.xplane`).
 """
 
 from __future__ import annotations
@@ -128,9 +132,11 @@ def _cpu_op(line_name: str, ev_name: str) -> bool:
     return (line_name.startswith("tf_XLA") and "::" not in ev_name)
 
 
-def read_xplane(path: str, cpu: bool = False) -> dict:
-    """Reduce one ``.xplane.pb`` (see :func:`reduce_events`). ``cpu`` takes
-    the device ops from the CPU backend's threads, for a rehearsal."""
+def read_events(path: str, cpu: bool = False):
+    """``(device_ops, host_spans, window)`` of one ``.xplane.pb`` in the
+    shapes :func:`reduce_events` takes; ``window`` is None where the trace
+    has no ``bench.window`` span. ``cpu`` takes the device ops from the CPU
+    backend's threads, for a rehearsal."""
     from jax.profiler import ProfileData
 
     pd = ProfileData.from_file(path)
@@ -155,12 +161,23 @@ def read_xplane(path: str, cpu: bool = False) -> dict:
                         window = (s, e)
                     elif ev.duration_ns > 0:
                         host_spans.append((ev.name, s, e))
-    if window is None:
-        raise RuntimeError(f"no {WINDOW_SPAN!r} span in {path}")
     if not device_ops:
         raise RuntimeError(f"no {OPS_LINE!r} line on any "
                            f"{DEVICE_PLANE_PREFIX}* plane in {path}")
-    return reduce_events(device_ops, host_spans, window)
+    return device_ops, host_spans, window
+
+
+def _window_events(path: str, cpu: bool = False):
+    device_ops, host_spans, window = read_events(path, cpu)
+    if window is None:
+        raise RuntimeError(f"no {WINDOW_SPAN!r} span in {path}")
+    return device_ops, host_spans, window
+
+
+def read_xplane(path: str, cpu: bool = False) -> dict:
+    """Reduce one ``.xplane.pb`` over its ``bench.window`` span (see
+    :func:`reduce_events` and :func:`read_events`)."""
+    return reduce_events(*_window_events(path, cpu))
 
 
 def profiler_spans():
@@ -220,9 +237,17 @@ class Session:
         jax.profiler.stop_trace()
 
     def reduce(self) -> dict:
+        """:func:`reduce_events` of the window, with the same events'
+        ``device_scopes`` and ``idle_by_span``
+        (:func:`bench.harness.xplane.reduce_scoped`)."""
+        from bench.harness import xplane
+
         paths = glob.glob(os.path.join(self.log_dir, "**", "*.xplane.pb"),
                           recursive=True)
         if not paths:
             raise RuntimeError(f"the profiler wrote no trace under "
                                f"{self.log_dir}")
-        return read_xplane(max(paths, key=os.path.getmtime), self.cpu)
+        path = max(paths, key=os.path.getmtime)
+        events = _window_events(path, self.cpu)
+        return dict(reduce_events(*events),
+                    **xplane.reduce_scoped(*events, xplane.tf_ops(path)))

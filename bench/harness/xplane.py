@@ -20,8 +20,8 @@ from __future__ import annotations
 
 import re
 
-from bench.harness.trace import (DEVICE_PLANE_PREFIX, OPS_LINE, WINDOW_SPAN,
-                                 gaps_between, label_gap, self_times,
+from bench.harness.trace import (DEVICE_PLANE_PREFIX, gaps_between,
+                                 label_gap, read_events, self_times,
                                  union_length)
 
 TF_OP = "tf_op"
@@ -161,30 +161,7 @@ def _longest_first(seconds: dict) -> dict:
 def read_scoped(path: str) -> dict:
     """:func:`reduce_scoped` of one ``.xplane.pb`` over its ``bench.window``
     span (the whole trace where it has none)."""
-    from jax.profiler import ProfileData
-
-    pd = ProfileData.from_file(path)
-    device_ops, host_spans, window = {}, [], None
-    for plane in pd.planes:
-        if plane.name.startswith(DEVICE_PLANE_PREFIX):
-            for line in plane.lines:
-                if line.name == OPS_LINE:
-                    device_ops[plane.name] = [
-                        (ev.name, ev.start_ns * 1e-9,
-                         (ev.start_ns + ev.duration_ns) * 1e-9)
-                        for ev in line.events]
-        elif plane.name.startswith("/host:"):
-            for line in plane.lines:
-                for ev in line.events:
-                    s = ev.start_ns * 1e-9
-                    e = s + ev.duration_ns * 1e-9
-                    if ev.name == WINDOW_SPAN:
-                        window = (s, e)
-                    elif ev.duration_ns > 0:
-                        host_spans.append((ev.name, s, e))
-    if not device_ops:
-        raise RuntimeError(f"no {OPS_LINE!r} line on any "
-                           f"{DEVICE_PLANE_PREFIX}* plane in {path}")
+    device_ops, host_spans, window = read_events(path)
     if window is None:
         times = [t for ops in device_ops.values() for _, s, e in ops
                  for t in (s, e)]

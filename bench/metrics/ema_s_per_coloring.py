@@ -1,0 +1,8 @@
+"""Device seconds under the ``kernel.ema`` scope (every plan node's eMA)
+in the traced window, over the colorings answered in it."""
+
+from bench.harness.readers import kernel_s_per_coloring
+
+
+def read(run):
+    return kernel_s_per_coloring(run, "kernel.ema")

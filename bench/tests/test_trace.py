@@ -2,10 +2,14 @@
 
 import glob
 import os
+import shutil
 
 import pytest
 
-from bench.harness import trace
+from bench.harness import trace, xplane
+
+SCOPED = os.path.join(os.path.dirname(__file__), "data",
+                      "tpu_scoped_u5.xplane.pb")
 
 
 def test_union_and_gaps_on_recorded_events():
@@ -61,6 +65,9 @@ def test_recorded_cpu_trace(tmp_path):
     r = s.reduce()
     assert 0 < r["busy_s"] <= r["window_s"]
     assert r["device_ops"]
+    # the CPU's ops carry no tf_op: all their time is unscoped
+    assert set(r["device_scopes"]) == {"unscoped"}
+    assert sum(r["device_scopes"].values()) == pytest.approx(r["busy_s"])
     assert glob.glob(os.path.join(tmp_path, "**", "*.xplane.pb"),
                      recursive=True)
 
@@ -78,3 +85,26 @@ def test_recorded_tpu_trace():
     # the three longest gaps are the host's sleeps between the steps
     assert [n for n, _ in r["idle_gaps"][:3]] == ["no host span"] * 3
     assert sum(g for _, g in r["idle_gaps"][:3]) > 0.015
+
+
+def test_session_reduction_adds_scopes_and_keeps_the_rest(tmp_path):
+    """A session's reduction of the recorded u5 trace: the four keys of
+    :func:`trace.read_xplane`, with the values it gave before the scopes
+    were read, and :func:`xplane.read_scoped`'s two over the same window."""
+    profile = tmp_path / "plugins" / "profile" / "run"
+    profile.mkdir(parents=True)
+    shutil.copy(SCOPED, profile / "host.xplane.pb")
+    r = trace.Session(str(tmp_path)).reduce()
+    assert set(r) == {"busy_s", "window_s", "device_ops", "idle_gaps",
+                      "device_scopes", "idle_by_span"}
+    old = trace.read_xplane(SCOPED)
+    assert {k: r[k] for k in old} == old
+    assert r["busy_s"] == 0.00021861300000000639
+    assert r["window_s"] == 0.01405356
+    assert r["device_ops"][0] == ["fusion.9 f32[512,10] fusion",
+                                  8.0087999999999e-05]
+    assert r["idle_gaps"][0] == ["host.pause", 0.006775931999999998]
+    scoped = xplane.read_scoped(SCOPED)
+    assert r["device_scopes"] == scoped["device_scopes"]
+    assert r["idle_by_span"] == scoped["idle_by_span"]
+    assert sum(r["device_scopes"].values()) == pytest.approx(r["busy_s"])

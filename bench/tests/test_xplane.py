@@ -1,11 +1,15 @@
 """The ``tf_op`` decoder and the reductions by device scope and host span."""
 
+import importlib.util
 import os
 import re
 
 import pytest
 
 from bench.harness import trace, xplane
+from bench.harness.cell import BENCH_DIR
+from bench.harness.runner import RunData
+from bench.harness.traffic import Request, Window
 
 DATA = os.path.join(os.path.dirname(__file__), "data")
 SEGMENT_SUM = os.path.join(DATA, "tpu_segment_sum.xplane.pb")
@@ -121,3 +125,51 @@ def test_recorded_scoped_tpu_trace():
     assert s["idle_by_span"]["host.pause"] > 0.008
     assert sum(s["idle_by_span"].values()) == pytest.approx(
         r["window_s"] - r["busy_s"])
+
+
+def _reader(name):
+    spec = importlib.util.spec_from_file_location(
+        name, BENCH_DIR / "metrics" / f"{name}.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+def _request(idx, t_send, status="done", **answer):
+    r = Request(idx, "u7", idx, "batch", None, 2, t_send=t_send,
+                t_done=t_send + 0.2, status=status)
+    if status == "done":
+        r.answer = {"iterations": 2, "estimate": 1.0, **answer}
+    return r
+
+
+def _run(traced: bool) -> RunData:
+    reqs = [_request(0, 10.1), _request(1, 10.4),
+            _request(2, 10.7, from_cache=True),      # no colorings of its own
+            _request(3, 9.0),                        # sent before the trace
+            _request(4, 10.9, status="failed")]
+    trace_ = {
+        "started": 10.0, "stopped": 11.0, "busy_s": 0.555,
+        "window_s": 1.0,
+        "device_scopes": {"plan.node3/kernel.spmm": 0.3,
+                          "plan.node1/kernel.spmm": 0.2,
+                          "plan.node3/kernel.ema": 0.03,
+                          "plan.node1/kernel.ema": 0.01,
+                          "kernel.root": 0.01, "unscoped": 0.005},
+        "idle_by_span": {"service.idle": 0.3, "service.retire": 0.12,
+                         "no host span": 0.03}}
+    return RunData(20.0, Window(reqs, 9.0, 11.0, 11.1),
+                   trace_ if traced else None, None)
+
+
+@pytest.mark.parametrize("metric, value", [
+    # 2 requests of 2 colorings drew their own samples in the trace
+    ("spmm_s_per_coloring", (0.3 + 0.2) / 4),
+    ("ema_s_per_coloring", (0.03 + 0.01) / 4),
+    # 3 requests answered in the trace; waiting for work is not counted
+    ("service_idle_s_per_request", (0.12 + 0.03) / 3),
+])
+def test_scope_readers(metric, value):
+    read = _reader(metric)
+    assert read(_run(True)) == pytest.approx(value)
+    assert read(_run(False)) is None
