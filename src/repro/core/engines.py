@@ -235,6 +235,11 @@ class CountingEngine:
         self._materialize()
         self.work = self._estimate_work()
         self.spmm_cols_per_coloring = self._spmm_cols_per_coloring()
+        # what the last program :meth:`warm` compiled holds (see
+        # :meth:`execution_report`); empty until a width is compiled
+        self.compiled_report: dict = {}
+        for name, value in self.execution_decision().items():
+            _metrics.gauge(f"engine_{name}", **self._mem_labels).set(value)
         # dispatch accounting (service/benchmark introspection): device calls
         # through the batched pipeline, coloring rows computed by them
         # (padding rows included — they are real device work), and SpMM
@@ -638,11 +643,82 @@ class CountingEngine:
         bs = self._dispatch_width(int(n_ids), batch_size)
         if bs < 1 or bs in self._warm_widths:
             return
-        with _tracing.span("engine.warm", engine=self.engine, batch=bs):
-            self._seeded().lower(
+        with _tracing.span("engine.warm", engine=self.engine,
+                           batch=bs) as sp:
+            compiled = self._seeded().lower(
                 self._operands(), jax.ShapeDtypeStruct((), jnp.int32),
                 jax.ShapeDtypeStruct((bs,), jnp.int32)).compile()
+            self._record_compiled(bs, compiled)
+            sp.set(**self.compiled_report)
         self._warm_widths.add(bs)
+
+    def execution_decision(self) -> dict:
+        """What the memory model decided for this engine: colorings a
+        dispatch, how many plan nodes run colorset-chunked, and the
+        modeled peak live table bytes of one dispatch. Published as the
+        ``engine_<key>`` gauges when the engine is built."""
+        return {"batch_size": self.batch_size,
+                "chunked_nodes": len(self.schedule.chunks),
+                "modeled_peak_bytes": self.peak_table_bytes}
+
+    def execution_report(self) -> dict:
+        """:meth:`execution_decision` with the template's name and, once
+        :meth:`warm` has compiled a dispatch width, what the last program
+        it compiled holds: ``width`` (colorings), ``compiled_temp_bytes``
+        (XLA's temporaries; absent where the backend gives no memory
+        analysis) and ``spmm_steps`` (:meth:`segment_spmm_steps`)."""
+        return {"template": self._mem_labels["template"],
+                **self.execution_decision(), **self.compiled_report}
+
+    def _record_compiled(self, width: int, compiled) -> None:
+        """Publish what the program compiled for ``width`` colorings holds
+        as :attr:`compiled_report` and the ``engine_compiled_temp_bytes``
+        and per-node ``engine_spmm_steps`` gauges. Host-side only: the
+        compiled program is not touched."""
+        steps = self.segment_spmm_steps(width)
+        rep: dict = {"width": width}
+        try:
+            rep["compiled_temp_bytes"] = int(
+                compiled.memory_analysis().temp_size_in_bytes)
+        except (AttributeError, NotImplementedError, RuntimeError):
+            pass                      # no analysis on this backend
+        else:
+            _metrics.gauge("engine_compiled_temp_bytes", **self._mem_labels
+                           ).set(rep["compiled_temp_bytes"])
+        rep["spmm_steps"] = steps
+        for idx, n in steps.items():
+            _metrics.gauge("engine_spmm_steps", node=idx,
+                           **self._mem_labels).set(n)
+        self.compiled_report = rep
+
+    def segment_spmm_steps(self, width: int) -> dict[int, int]:
+        """``{plan node: steps}`` of the segment SpMM in one dispatch of
+        ``width`` colorings, keyed by the node whose ``plan.node<i>`` scope
+        runs the SpMM: the first consumer of each y-cached passive table
+        (one SpMM of ``width x C(k, t_p)`` rows), and every colorset-chunked
+        node (one SpMM per chunk). Fused nodes, shared-passive group
+        members among them, run no segment SpMM, nor does any engine but
+        ``pgbsc`` on the ``segment`` method (empty dict)."""
+        if self.engine != "pgbsc" or self.spmm_method != "segment":
+            return {}
+        sched = self.schedule
+        chunks = sched.chunk_map
+        out: dict[int, int] = {}
+        seen: set[int] = set()
+        for idx in sched.order:
+            node = self.plan.nodes[idx]
+            if node.is_leaf:
+                continue
+            q = chunks.get(idx, 1)
+            rows = comb(self.k, self.plan.nodes[node.passive].size)
+            if q > 1:
+                out[idx] = q * spmm_ops.segment_steps(
+                    width * -(-rows // q), self.g.m, self.dtype)
+            elif idx not in sched.fused_set and node.passive not in seen:
+                seen.add(node.passive)
+                out[idx] = spmm_ops.segment_steps(width * rows, self.g.m,
+                                                  self.dtype)
+        return out
 
     def estimate(self, n_iters: int, seed: int = 0,
                  start_iteration: int = 0,

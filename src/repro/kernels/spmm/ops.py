@@ -33,7 +33,8 @@ from repro.kernels.spmm.pallas_gather import spmm_gather_pallas
 from repro.obs import metrics as _metrics
 from repro.obs import tracing as _tracing
 
-__all__ = ["prepare", "spmm", "spmm_row_chunks", "SpmmPrep", "METHODS"]
+__all__ = ["prepare", "spmm", "spmm_row_chunks", "segment_steps",
+           "SpmmPrep", "METHODS"]
 
 METHODS = ("segment", "ell", "dense", "pallas_gather", "pallas_bsr")
 
@@ -140,6 +141,14 @@ def _segment_row_chunk(c: int, e: int, itemsize: int) -> int:
     if c <= fit:
         return c
     return fit // 8 * 8 if fit >= 8 else max(fit, 1)
+
+
+def segment_steps(rows: int, e: int, dtype) -> int:
+    """Steps the segment SpMM takes over a ``(rows, N)`` table of storage
+    ``dtype`` on ``e`` edge slots: one unless the gathered operand passes
+    ``_SEGMENT_GATHER_BUDGET_BYTES`` (:func:`_segment_row_chunk`)."""
+    itemsize = jnp.dtype(ema_ops.accum_dtype(dtype)).itemsize
+    return -(-rows // _segment_row_chunk(rows, e, itemsize))
 
 
 def _spmm_segment(m: jnp.ndarray, src, dst, n: int) -> jnp.ndarray:

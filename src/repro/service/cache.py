@@ -117,9 +117,11 @@ class EngineCache:
         _metrics.counter("engine_cache_lookups_total", result="miss").inc()
         _faults.inject("engine.build",
                        context=f"{g.fingerprint[:12]}:{engine}:{plan}")
-        with _tracing.span("engine_cache.build", engine=engine, plan=plan):
+        with _tracing.span("engine_cache.build", engine=engine,
+                           plan=plan) as sp:
             eng = build_engine(g, _template_build_arg(template), engine,
                                plan=plan, **build_kw)
+            sp.set(**eng.execution_decision())
         self.builds += 1
         _metrics.counter("engine_cache_builds_total").inc()
         self._engines[k] = eng
@@ -150,9 +152,14 @@ class EngineCache:
         return len(self._engines)
 
     def stats(self) -> dict:
+        """Lookup and build counts, and each resident engine's
+        :meth:`~repro.core.engines.CountingEngine.execution_report` (what
+        the memory model decided and what its compiled program holds)."""
         return {"hits": self.hits, "misses": self.misses,
                 "builds": self.builds, "evictions": self.evictions,
-                "resident": len(self._engines)}
+                "resident": len(self._engines),
+                "engines": [e.execution_report()
+                            for e in list(self._engines.values())]}
 
 
 class EstimateCache:

@@ -317,6 +317,122 @@ class TestWatermark:
         assert meas and model and meas[0] <= model[0]
 
 
+# ------------------------------- executor decision and compiled program
+def _lower_seeded(eng, width):
+    import jax
+    import jax.numpy as jnp
+    return eng._seeded().lower(
+        eng._operands(), jax.ShapeDtypeStruct((), jnp.int32),
+        jax.ShapeDtypeStruct((width,), jnp.int32))
+
+
+class TestExecutionGauges:
+    def test_decision_gauges_equal_pick_execution(self, registry):
+        from repro.core import executor as pexec
+        g = _graph(64)
+        eng = build_engine(g, "u12", "pgbsc", plan="optimized")
+        choice = pexec.pick_execution(eng.plan, 12, g.n)
+        gauges = metrics.snapshot()["gauges"]
+        labels = '{engine="pgbsc",k="12",template="u12"}'
+        assert gauges["engine_batch_size" + labels] == choice.batch_size
+        assert gauges["engine_chunked_nodes" + labels] == \
+            len(choice.schedule.chunks) == 0
+        assert gauges["engine_modeled_peak_bytes" + labels] == \
+            choice.peak_bytes
+
+    @pytest.mark.parametrize("tpl,budget,want", [
+        # u12 at the benchmark's ratio of gather budget to edge slots
+        # (4 GiB over 1.8M slots: 592-row steps): its 792-column passive
+        # tables take 3 steps at 2 colorings, every other table one
+        ("u12", None, {1: 1, 3: 1, 5: 3, 7: 3, 9: 1, 11: 1}),
+        # u13 squeezed into colorset chunks: one SpMM per chunk
+        ("u13", 500_000, {1: 1, 3: 1, 5: 1716, 6: 2, 7: 6}),
+    ], ids=["u12", "u13-chunked"])
+    def test_segment_steps_equal_row_chunks(self, registry, monkeypatch,
+                                            tpl, budget, want):
+        from math import comb
+
+        from repro.kernels.spmm import ops as spmm_ops
+        g = _graph(64)
+        monkeypatch.setattr(spmm_ops, "_SEGMENT_GATHER_BUDGET_BYTES",
+                            596 * g.m * 4)
+        eng = build_engine(g, tpl, "pgbsc", plan="optimized",
+                           memory_budget_bytes=budget)
+        chunks = eng.schedule.chunk_map
+        steps = eng.segment_spmm_steps(2)
+        assert steps == want
+        for idx, n in steps.items():
+            q = chunks.get(idx, 1)
+            node = eng.plan.nodes[idx]
+            rows = 2 * -(-comb(eng.k, eng.plan.nodes[node.passive].size)
+                         // q)
+            per_call = -(-rows // spmm_ops._segment_row_chunk(rows, g.m, 4))
+            assert n == q * per_call
+        # the traced program's SpMMs take those steps: one traced call
+        # per y-cached table or chunked node (a chunk scan runs it q times)
+        orig, traced = spmm_ops._segment_row_chunk, []
+
+        def spy(c, e, itemsize):
+            rc = orig(c, e, itemsize)
+            traced.append(-(-c // rc))
+            return rc
+
+        monkeypatch.setattr(spmm_ops, "_segment_row_chunk", spy)
+        _lower_seeded(eng, 2)
+        assert sorted(traced) == sorted(
+            n // chunks.get(i, 1) for i, n in steps.items())
+
+    def test_cache_stats_and_spans_carry_them(self, registry, tracer):
+        from repro.service import EngineCache
+        cache = EngineCache()
+        eng = cache.get(_graph(64), "u12")
+        eng.warm(2)
+        rep = eng.execution_report()
+        assert cache.stats()["engines"] == [rep]
+        assert rep["template"] == "u12" and rep["width"] == 2
+        assert rep["batch_size"] == eng.batch_size
+        assert rep["modeled_peak_bytes"] == eng.peak_table_bytes
+        assert rep["spmm_steps"] == eng.segment_spmm_steps(2)
+        # the CPU backend gives a memory analysis
+        assert rep["compiled_temp_bytes"] > 0
+        gauges = metrics.snapshot()["gauges"]
+        labels = '{engine="pgbsc",k="12",template="u12"}'
+        assert gauges["engine_compiled_temp_bytes" + labels] == \
+            rep["compiled_temp_bytes"]
+        assert gauges['engine_spmm_steps{engine="pgbsc",k="12",node="5",'
+                      'template="u12"}'] == rep["spmm_steps"][5]
+        spans = {}
+
+        def walk(sp):
+            spans[sp.name] = sp.attrs
+            for c in sp.children:
+                walk(c)
+
+        for r in tracer.roots:
+            walk(r)
+        assert spans["engine_cache.build"]["batch_size"] == eng.batch_size
+        assert spans["engine_cache.build"]["chunked_nodes"] == 0
+        assert spans["engine.warm"]["spmm_steps"] == rep["spmm_steps"]
+        assert spans["engine.warm"]["compiled_temp_bytes"] == \
+            rep["compiled_temp_bytes"]
+
+    def test_recording_leaves_u7_hlo_unchanged(self, registry,
+                                               monkeypatch):
+        from repro.core.engines import CountingEngine
+        g = _graph(64)
+        eng = build_engine(g, "u7", "pgbsc", plan="optimized")
+        eng.warm(2)
+        with_rec = _lower_seeded(eng, 2).as_text()
+        monkeypatch.setattr(CountingEngine, "_record_compiled",
+                            lambda self, width, compiled: None)
+        monkeypatch.setattr(CountingEngine, "execution_decision",
+                            lambda self: {})
+        bare = build_engine(g, "u7", "pgbsc", plan="optimized")
+        bare.warm(2)
+        assert bare.compiled_report == {}
+        assert _lower_seeded(bare, 2).as_text() == with_rec
+
+
 # ------------------------------------------------------- service plumbing
 class TestServiceObservability:
     def test_estimate_cache_stats_contract(self, registry):

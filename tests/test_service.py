@@ -58,7 +58,8 @@ class TestEngineCache:
         g3 = _graph(seed=2)     # different content
         e1 = cache.get(g1, "u3")
         assert cache.stats() == {"hits": 0, "misses": 1, "builds": 1,
-                                 "evictions": 0, "resident": 1}
+                                 "evictions": 0, "resident": 1,
+                                 "engines": [e1.execution_report()]}
         assert cache.get(g2, "u3") is e1          # content hash, not identity
         assert cache.get(g1, "u3", plan="plain") is not e1
         assert cache.get(g3, "u3") is not e1
